@@ -1,4 +1,4 @@
-"""smollm-360m — llama-arch small dense LM [hf:HuggingFaceTB/SmolLM-135M]."""
+"""smollm-360m — llama-arch small dense LM [hf:HuggingFaceTB/SmolLM-360M]."""
 from repro.configs.base import ArchConfig, VerticalConfig, register
 
 SMOLLM_360M = register(
@@ -14,6 +14,6 @@ SMOLLM_360M = register(
         rope_theta=10000.0,
         tie_embeddings=True,
         vertical=VerticalConfig(num_clients=4, tower_layers=2, merge="avg"),
-        source="hf:HuggingFaceTB/SmolLM-135M",
+        source="hf:HuggingFaceTB/SmolLM-360M",
     )
 )

@@ -10,10 +10,13 @@ TPU adaptation notes (DESIGN.md §6): tiles are (block_b, block_d) with
 block_d a multiple of 128 (lane width) so the VPU reduction over K is fully
 vectorized; K is small (2-8 clients, paper §4) and is unrolled.
 
-``concat`` (the last merge off the fast path, ROADMAP) is a gather, not a
-reduction: a third grid axis walks the K clients and DMAs each (bB, bD)
-tile straight into its column block of the (B, K*D) output — one read of
-the stack, one contiguous write, live-masking fused in.
+``concat`` is a gather, not a reduction: each grid step copies a (K, bB, D)
+block of the stack into one (bB, K*D) output row block, client i at static
+columns [i*D, (i+1)*D) — one read of the stack, one contiguous write,
+live-masking fused in.  The row block spans the whole K*D width, so the cut
+width D need not be a multiple of the 128-lane tile (smollm-360m's K=4 cut
+is 240 wide), and the (K,) live mask sits in SMEM, where the kernel reads
+it as scalars.
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -3.0e38
 
@@ -56,65 +60,52 @@ def _merge_kernel(stacked_ref, live_ref, out_ref, *, strategy: str, k: int):
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
-def _concat_block_d(block_d: int, d: int) -> int:
-    """concat tiles must align with the per-client D boundaries in the
-    (B, K*D) output grid, so the tile width has to divide D; fall back to a
-    whole client row when it doesn't (cut widths are modest)."""
-    bd = min(block_d, d)
-    return bd if d % bd == 0 else d
+def _concat_kernel(live_ref, stacked_ref, out_ref, *, k: int, d: int):
+    """Fused gather-concat over one row block: client i's (bB, D) tile lands
+    at columns [i*D, (i+1)*D) of the (bB, K*D) output row; dropped clients
+    write zeros.  One HBM read of the stack, one contiguous write."""
+    for i in range(k):  # static column offsets: lane-unaligned D is fine
+        out_ref[:, i * d:(i + 1) * d] = (
+            stacked_ref[i].astype(jnp.float32) * live_ref[i]
+        ).astype(out_ref.dtype)
 
 
-def _concat_kernel(stacked_ref, live_ref, out_ref):
-    """Fused gather-concat: client k's (bB, bD) tile lands at column block
-    k*D + j*bD of the (B, K*D) output; dropped clients write zeros.  One
-    HBM read of the stack, one contiguous write — no intermediate
-    per-client copies like the jnp concatenate lowering."""
-    k = pl.program_id(2)
-    l = live_ref[k]
-    out_ref[...] = (stacked_ref[0].astype(jnp.float32) * l).astype(
-        out_ref.dtype)
-
-
-def _concat_fwd_call(stacked, live, *, block_b, block_d, interpret):
+def _concat_fwd_call(stacked, live, *, block_b, interpret):
     K, B, D = stacked.shape
-    bb, bd = min(block_b, B), _concat_block_d(block_d, D)
-    n_d = D // bd
-    grid = (pl.cdiv(B, bb), n_d, K)
+    bb = min(block_b, B)
     return pl.pallas_call(
-        _concat_kernel,
-        grid=grid,
+        functools.partial(_concat_kernel, k=K, d=D),
+        grid=(pl.cdiv(B, bb),),
         in_specs=[
-            pl.BlockSpec((1, bb, bd), lambda i, j, k: (k, i, j)),
-            pl.BlockSpec((K,), lambda i, j, k: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((K, bb, D), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((bb, bd), lambda i, j, k: (i, k * n_d + j)),
+        out_specs=pl.BlockSpec((bb, K * D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K * D), stacked.dtype),
         interpret=interpret,
-    )(stacked, live)
+    )(live, stacked)
 
 
-def _concat_bwd_kernel(live_ref, g_ref, dx_ref):
-    """Jacobian splitting for concat: client k's gradient is its own column
+def _concat_bwd_kernel(live_ref, g_ref, dx_ref, *, k: int, d: int):
+    """Jacobian splitting for concat: client i's gradient is its own column
     slice of the merged gradient (zeroed when it was dropped)."""
-    k = pl.program_id(2)
-    dx_ref[0] = (g_ref[...].astype(jnp.float32) * live_ref[k]).astype(
-        dx_ref.dtype)
+    for i in range(k):
+        dx_ref[i] = (g_ref[:, i * d:(i + 1) * d].astype(jnp.float32)
+                     * live_ref[i]).astype(dx_ref.dtype)
 
 
-def _concat_bwd_call(live, g, *, k, block_b, block_d, interpret):
+def _concat_bwd_call(live, g, *, k, block_b, interpret):
     B = g.shape[0]
     D = g.shape[1] // k
-    bb, bd = min(block_b, B), _concat_block_d(block_d, D)
-    n_d = D // bd
-    grid = (pl.cdiv(B, bb), n_d, k)
+    bb = min(block_b, B)
     return pl.pallas_call(
-        _concat_bwd_kernel,
-        grid=grid,
+        functools.partial(_concat_bwd_kernel, k=k, d=D),
+        grid=(pl.cdiv(B, bb),),
         in_specs=[
-            pl.BlockSpec((k,), lambda i, j, kk: (0,)),
-            pl.BlockSpec((bb, bd), lambda i, j, kk: (i, kk * n_d + j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((bb, k * D), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bb, bd), lambda i, j, kk: (kk, i, j)),
+        out_specs=pl.BlockSpec((k, bb, D), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((k, B, D), g.dtype),
         interpret=interpret,
     )(live, g)
@@ -124,7 +115,7 @@ def _merge_pool_fwd_call(stacked, live, *, strategy, block_b, block_d,
                          interpret):
     if strategy == "concat":
         return _concat_fwd_call(stacked, live, block_b=block_b,
-                                block_d=block_d, interpret=interpret)
+                                interpret=interpret)
     K, B, D = stacked.shape
     bb, bd = min(block_b, B), min(block_d, D)
     grid = (pl.cdiv(B, bb), pl.cdiv(D, bd))
@@ -217,7 +208,7 @@ def _bwd(strategy, block_b, block_d, interpret, res, g):
     if strategy == "concat":
         dx = _concat_bwd_call(live, g.astype(stacked.dtype),
                               k=stacked.shape[0], block_b=block_b,
-                              block_d=block_d, interpret=interpret)
+                              interpret=interpret)
     else:
         dx = _merge_pool_bwd_call(stacked, live, out, g.astype(stacked.dtype),
                                   strategy=strategy, block_b=block_b,
@@ -237,7 +228,12 @@ def merge_pool(stacked, live=None, *, strategy: str = "avg",
     Result (B, D) for the reductions, (B, K*D) for the fused gather-concat
     (dropped clients contribute zero columns).  Differentiable: the backward
     pass is a second fused Pallas kernel implementing the paper's jacobian
-    splitting (§3) — column-slice routing for concat."""
+    splitting (§3) — column-slice routing for concat.
+
+    ``block_d`` tiles only the reductions.  ``concat`` blocks span the whole
+    K*D row, untiled, so its VMEM use is about 2*2*K*block_b*D*itemsize
+    bytes (input and output blocks, double-buffered): 14 MiB at the widest
+    registered cut (K*D = 7168, f32, block_b=128)."""
     K, B, D = stacked.shape
     if live is None:
         live = jnp.ones((K,), jnp.float32)

@@ -1,6 +1,9 @@
-"""Jit'd wrappers that select the Pallas kernel on TPU and the pure-jnp
-oracle elsewhere (this container lowers to CPU, where the TPU kernels run
-only under interpret=True — used by tests)."""
+"""Dispatch between the Pallas TPU kernels and their pure-jnp oracles.
+
+On a TPU backend the model path runs the compiled Pallas kernels (``python
+chip_smoke.py`` checks that the compiled programs contain them).  On any
+other backend the default is the jnp oracle from ``kernels.ref``; tests run
+the kernels themselves on the CPU with ``interpret=True``."""
 from __future__ import annotations
 
 import jax

@@ -335,8 +335,10 @@ def main(argv=None):
                 f"{cfg.vertical.num_clients} clients"
             )
 
+    from repro.launch.compile_cache import setup_compile_cache
     from repro.models.backbone import param_count
 
+    setup_compile_cache()
     n_params = param_count(cfg)
     print(f"arch={cfg.name} family={cfg.family} params={n_params/1e6:.1f}M "
           f"vertical={cfg.vertical}")
@@ -364,6 +366,10 @@ def main(argv=None):
                 "transport": args.transport,
                 "step_time_s": report.step_time_s,
                 "staleness": getattr(report, "staleness", 0),
+                # where each side really computed: multiproc towers run on
+                # the host CPU even when role 0 holds an accelerator
+                "role0_platform": jax.default_backend(),
+                "tower_platform": report.tower_platform,
                 "deadline_misses": report.total_misses,
                 "cut_bytes_per_client": report.cut_bytes_per_client,
             }

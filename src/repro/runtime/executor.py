@@ -119,6 +119,8 @@ class ExecReport:
     # steps submitted after this one before it was collected: the tower
     # params' delayed-gradient lag (0 = serial semantics, W-1 at window W)
     staleness: int = 0
+    # JAX platform the towers computed on (Transport.tower_platform)
+    tower_platform: str = ""
 
     @property
     def total_misses(self) -> int:
@@ -879,4 +881,5 @@ class Executor:
                 strategy, per_mb_elements, K, itemsize),
             deadline_s=deadline_s,
             staleness=staleness,
+            tower_platform=self.transport.tower_platform,
         )

@@ -26,6 +26,13 @@ class Transport:
 
     num_clients: int
 
+    @property
+    def tower_platform(self) -> str:
+        """JAX platform the tower workers compute on.  In-process workers
+        share the caller's backend; process backends report their
+        children's."""
+        return jax.default_backend()
+
     def submit(self, client: int, request: dict) -> None:
         raise NotImplementedError
 

@@ -13,7 +13,6 @@ launcher looks like anyway.
 """
 from __future__ import annotations
 
-import os
 import pickle
 import queue
 import socket
@@ -23,6 +22,9 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 import multiprocessing as mp
+
+import jax
+import numpy as np
 
 from repro.transport.base import Transport
 
@@ -53,11 +55,6 @@ def _to_numpy(tree):
     """Convert jax arrays to numpy at the wire boundary; python scalars,
     strings and numpy arrays pass through untouched (dict keys like
     ``step``/``mb`` must stay hashable ints on the far side)."""
-    # imports are lazy so a spawned child can pin JAX_PLATFORMS before
-    # jax initializes a backend
-    import jax
-    import numpy as np
-
     def conv(leaf):
         return np.asarray(leaf) if isinstance(leaf, jax.Array) else leaf
 
@@ -77,13 +74,19 @@ class WorkerSpec:
 
 
 def _client_main(spec: WorkerSpec, client_id: int, port: int) -> None:
-    # children compute towers on CPU; keep any accelerator for role 0
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # children compute towers on the host CPU, whatever the environment
+    # says: an accelerator belongs to one process, and role 0 (the parent)
+    # already holds it.  The hello names the platform, so role 0 reports
+    # where the towers really ran.  Importing this module has
+    # already read JAX_PLATFORMS, so the pin goes through jax.config; no
+    # backend is up before the worker is built.
+    jax.config.update("jax_platforms", "cpu")
     worker = spec.build(client_id, **spec.kwargs)
     sock = socket.create_connection(("127.0.0.1", port))
     sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     try:
-        send_msg(sock, {"op": "hello", "client": client_id})
+        send_msg(sock, {"op": "hello", "client": client_id,
+                        "platform": jax.default_backend()})
         while True:
             request = recv_msg(sock)
             try:
@@ -126,6 +129,7 @@ class MultiprocTransport(Transport):
 
         # accept all K hellos (children import jax, so be patient)
         self._listener.settimeout(connect_timeout_s)
+        platforms = set()
         try:
             for _ in range(self.num_clients):
                 conn, _ = self._listener.accept()
@@ -133,11 +137,13 @@ class MultiprocTransport(Transport):
                 hello = recv_msg(conn)
                 assert hello["op"] == "hello"
                 self._conns[hello["client"]] = conn
+                platforms.add(hello["platform"])
         except socket.timeout:
             self.close()
             raise TimeoutError(
                 f"not all {self.num_clients} clients connected within "
                 f"{connect_timeout_s}s")
+        self._tower_platform = ",".join(sorted(platforms))
 
         self._readers = [
             threading.Thread(target=self._read_loop, args=(k,), daemon=True,
@@ -146,6 +152,10 @@ class MultiprocTransport(Transport):
         ]
         for t in self._readers:
             t.start()
+
+    @property
+    def tower_platform(self) -> str:
+        return self._tower_platform
 
     def _read_loop(self, client: int) -> None:
         conn = self._conns[client]

@@ -88,5 +88,5 @@ RESPONSE_OPS: dict[str, str] = {
     "error": "worker exception surfaced by the transport {error}",
     # transport-level: a multiproc child's first frame after connecting,
     # mapping its socket to a client id (never reaches TowerWorker.handle)
-    "hello": "multiproc connection handshake {client}",
+    "hello": "multiproc connection handshake {client, platform}",
 }

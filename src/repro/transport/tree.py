@@ -60,6 +60,10 @@ class TreeRouter(Transport):
 
     # -- transport contract ---------------------------------------------------
 
+    @property
+    def tower_platform(self) -> str:
+        return self.base.tower_platform
+
     def submit(self, client: int, request: dict) -> None:
         self.base.submit(client, request)
         if self._inline:

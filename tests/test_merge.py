@@ -169,12 +169,7 @@ LIVE_COLLECTIVE_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    try:  # jax >= 0.6: top-level export, replication check renamed
-        from jax import shard_map
-        _sm_kw = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        _sm_kw = {"check_rep": False}
+    from jax import shard_map
     from repro.core import merge as merge_lib
 
     mesh = jax.make_mesh((2, 4), ("data", "client"))
@@ -192,7 +187,7 @@ LIVE_COLLECTIVE_SCRIPT = textwrap.dedent("""
         f = shard_map(local_fn, mesh=mesh,
                       in_specs=(P("client", "data", None), P("client")),
                       out_specs=P(None, "data", None),
-                      **_sm_kw)
+                      check_vma=False)
         got = f(x, live)[0]
         want = merge_lib.merge_stacked(x, strategy, live_mask=live)
         np.testing.assert_allclose(got, want, rtol=tol, atol=tol)

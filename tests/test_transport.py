@@ -239,6 +239,7 @@ def test_multiproc_loopback_matches_protocol_and_costs():
     np.testing.assert_allclose(res.loss, loss_s, atol=1e-5, rtol=1e-5)
     _assert_trees_close((res.tower_grads, res.server_grads), (tg_s, sg_s))
     assert res.report.transport == "MultiprocTransport"
+    assert res.report.tower_platform == "cpu"
 
     # per-role byte accounting over the real socket vs the analytic model
     want = costs.epoch_traffic(cfg, num_samples=batch, batch_size=batch)
@@ -249,6 +250,17 @@ def test_multiproc_loopback_matches_protocol_and_costs():
     assert ledger.received_by("role3") == want["role3"].received_bytes
     assert ledger.sent_by("role1") == want["role1"].sent_bytes * (
         cfg.num_clients - 1)
+
+
+def test_multiproc_children_pin_cpu_whatever_the_environment(monkeypatch):
+    """A spawned child computes on the host CPU even when the inherited
+    environment names an accelerator (which the parent would hold), and
+    its hello says so."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    specs = [WorkerSpec(build_mlp_worker, dict(cfg=TINY, batch=4))
+             for _ in range(TINY.num_clients)]
+    with MultiprocTransport(specs) as tr:
+        assert tr.tower_platform == "cpu"
 
 
 # ---------------------------------------------------------------------------
