@@ -63,7 +63,6 @@ from repro.core import compat
 from repro.core import compression as comp_lib
 from repro.core import merge as merge_lib
 from repro.core import straggler as straggler_lib
-from repro.core.merge import collective_bytes_per_merge
 from repro.core.protocol import Ledger, step_schedule
 from repro.core.secure_agg import KEYX_GROUP_BYTES
 from repro.runtime.deadline import AdaptiveDeadline
@@ -103,9 +102,11 @@ def tree_mean(trees):
 
 @dataclass
 class ExecReport:
-    """Measured (wall-clock) sibling of ``engine.SimReport`` — same field
-    contract, but ``step_time_s`` is real elapsed time on a real transport
-    and ``live`` reflects deadlines that actually fired."""
+    """Measured (wall-clock) sibling of ``engine.SimReport``: the fields the
+    two share mean the same, except that ``step_time_s`` is real elapsed
+    time on a real transport and ``live`` reflects deadlines that actually
+    fired.  It holds only what the step observed; the analytic
+    collective-bytes model is the simulator's alone."""
 
     mode: str
     transport: str
@@ -114,7 +115,6 @@ class ExecReport:
     live: list[list[float]]
     misses_per_client: list[int]
     cut_bytes_per_client: int
-    collective_bytes_per_client: int
     deadline_s: Optional[float] = None  # last deadline used (nowait)
     # steps submitted after this one before it was collected: the tower
     # params' delayed-gradient lag (0 = serial semantics, W-1 at window W)
@@ -512,7 +512,6 @@ class Executor:
         losses, aux_acc, server_grad_acc, live_matrix = [], [], [], []
         misses = [0] * K
         last_deadline: Optional[float] = self.static_deadline_s
-        cuts_in = None
 
         for m in range(M):
             live_row, deadline_used = self._gather(st, m, liveness)
@@ -589,9 +588,11 @@ class Executor:
                 loss = self.loss_fn(logits, labels_m) + aux
                 return loss, (logits, aux, new_ema)
 
-            (loss_m, (logits, aux_m, ema_state)), (sg, cut_grads) = \
-                jax.value_and_grad(server_loss, argnums=(0, 1), has_aux=True
-                                   )(server_params, cuts_in)
+            with jax.profiler.TraceAnnotation(
+                    "executor.server_step", step=st.step, mb=m):
+                (loss_m, (logits, aux_m, ema_state)), (sg, cut_grads) = \
+                    jax.value_and_grad(server_loss, argnums=(0, 1),
+                                       has_aux=True)(server_params, cuts_in)
             st.ledger.record_spec(schedule.head_out, logits)
             if self.server_aux:
                 # the aux scalar rides the role-0 -> role-3 loss exchange
@@ -599,50 +600,58 @@ class Executor:
                 aux_acc.append(aux_m)
             st.ledger.record_spec(schedule.head_jac, logits)
 
-            if self.agg_tree is not None:
-                # ONE backward per top-level client; relays forward the same
-                # jacobian down the tree (the additive merges give every
-                # subtree member the identical cut gradient — avg's 1/K is
-                # already inside cut_grads).  The ledger records every
-                # logical tree edge, and sent_jacs counts the backward each
-                # member receives via the router fan-out.
-                for i, t in enumerate(self.agg_tree.top_level):
-                    jac_out = cut_grads[i]
-                    for member in self.agg_tree.subtree(t):
-                        st.ledger.record_spec(schedule.jacs[member], jac_out)
-                        st.sent_jacs[member] += 1
-                    transport.submit(t, {
-                        "op": "backward", "step": st.step, "mb": m,
-                        "jac": jac_out,
-                    })
-                losses.append(loss_m)
-                server_grad_acc.append(sg)
-                continue
-            for spec in schedule.jacs:
-                k = spec.client
-                # serial/neutral semantics: jacobians flow to every client;
-                # no-wait: a missed deadline skips this microbatch's update
-                if self.drop_policy == "neutral" or live_row[k] > 0:
-                    jac_out = cut_grads[k]
-                    if self.compress is not None:
-                        # symmetric downlink compression with error
-                        # feedback: the residual this encode drops rides
-                        # into the next step's jacobian for the same
-                        # (client, mb) stream position
-                        jac_out, self._jac_residuals[(k, m)] = \
-                            comp_lib.compress_with_feedback(
-                                jac_out, self._jac_residuals.get((k, m)),
-                                self.compress, self.topk_fraction)
-                        st.ledger.record_spec_bytes(
-                            spec, comp_lib.payload_bytes(
-                                jac_out, self.compress, self.topk_fraction))
-                    else:
-                        st.ledger.record_spec(spec, jac_out)
-                    st.sent_jacs[k] += 1
-                    transport.submit(k, {
-                        "op": "backward", "step": st.step, "mb": m,
-                        "jac": jac_out,
-                    })
+            # (client, jacobian) per backward, shipped once the span below
+            # has closed: a transport that runs its workers on this thread
+            # (SimTransport) would otherwise nest their spans inside it
+            backwards = []
+            with jax.profiler.TraceAnnotation(
+                    "executor.jac_fanout", step=st.step, mb=m):
+                if self.agg_tree is not None:
+                    # ONE backward per top-level client; relays forward the
+                    # same jacobian down the tree (the additive merges give
+                    # every subtree member the identical cut gradient —
+                    # avg's 1/K is already inside cut_grads).  The ledger
+                    # records every logical tree edge, and sent_jacs counts
+                    # the backward each member receives via the router
+                    # fan-out.
+                    for i, t in enumerate(self.agg_tree.top_level):
+                        jac_out = cut_grads[i]
+                        for member in self.agg_tree.subtree(t):
+                            st.ledger.record_spec(schedule.jacs[member],
+                                                  jac_out)
+                            st.sent_jacs[member] += 1
+                        backwards.append((t, jac_out))
+                else:
+                    for spec in schedule.jacs:
+                        k = spec.client
+                        # serial/neutral semantics: jacobians flow to every
+                        # client; no-wait: a missed deadline skips this
+                        # microbatch's update
+                        if self.drop_policy != "neutral" and live_row[k] <= 0:
+                            continue
+                        jac_out = cut_grads[k]
+                        if self.compress is not None:
+                            # symmetric downlink compression with error
+                            # feedback: the residual this encode drops rides
+                            # into the next step's jacobian for the same
+                            # (client, mb) stream position
+                            jac_out, self._jac_residuals[(k, m)] = \
+                                comp_lib.compress_with_feedback(
+                                    jac_out, self._jac_residuals.get((k, m)),
+                                    self.compress, self.topk_fraction)
+                            st.ledger.record_spec_bytes(
+                                spec, comp_lib.payload_bytes(
+                                    jac_out, self.compress,
+                                    self.topk_fraction))
+                        else:
+                            st.ledger.record_spec(spec, jac_out)
+                        st.sent_jacs[k] += 1
+                        backwards.append((k, jac_out))
+            for k, jac_out in backwards:
+                transport.submit(k, {
+                    "op": "backward", "step": st.step, "mb": m,
+                    "jac": jac_out,
+                })
             losses.append(loss_m)
             server_grad_acc.append(sg)
 
@@ -652,7 +661,7 @@ class Executor:
                 "collect": collect_grads, "expected_jacs": st.sent_jacs[k],
             })
         while not all(st.done):
-            if not self._pump(None):
+            if not self._pump(st.step, None):
                 raise self._idle_error(
                     "awaiting step_done",
                     f"step {st.step}: {sum(st.done)}/{K} workers done")
@@ -665,7 +674,7 @@ class Executor:
         if report is None:
             report = self._build_report(
                 time.monotonic() - st.submit_t, live_matrix, misses,
-                st.ledger, cuts_in, last_deadline, staleness)
+                st.ledger, last_deadline, staleness)
         return ExecutionResult(loss, tower_grads, server_grads, st.ledger,
                                report, ema_state, aux, step=st.step)
 
@@ -683,11 +692,13 @@ class Executor:
 
     # -- the shared event pump ------------------------------------------------
 
-    def _pump(self, timeout: Optional[float]) -> bool:
+    def _pump(self, step: int, timeout: Optional[float]) -> bool:
         """Drain ONE transport response into its step's buffers; returns
         False on timeout/idle.  Safe under cross-step interleaving: every
-        response is routed by its ``(step, mb)`` key."""
-        got = self.transport.next_response(timeout)
+        response is routed by its ``(step, mb)`` key.  ``step`` is the step
+        role 0 is collecting, the ``transport.wait`` span's tag."""
+        with jax.profiler.TraceAnnotation("transport.wait", step=step):
+            got = self.transport.next_response(timeout)
         if got is None:
             return False
         k, resp = got
@@ -780,7 +791,7 @@ class Executor:
             # the O(K) -> O(F) role-0 serialization win
             need = len(self.agg_tree.top_level)
             while have() < need:
-                if not self._pump(None):
+                if not self._pump(st.step, None):
                     raise self._idle_error(
                         "awaiting tree frames",
                         f"step {st.step} mb {m}: {have()}/{need} top-level "
@@ -791,7 +802,7 @@ class Executor:
             # simulated clock: the transport delivers every cut; the given
             # matrix decides who made the merge
             while have() < K:
-                if not self._pump(None):
+                if not self._pump(st.step, None):
                     raise self._idle_error(
                         "awaiting cuts",
                         f"step {st.step} mb {m}: {have()}/{K} in")
@@ -799,7 +810,7 @@ class Executor:
 
         if self.mode != "nowait":
             while have() < K:
-                if not self._pump(None):
+                if not self._pump(st.step, None):
                     raise self._idle_error(
                         "awaiting cuts",
                         f"step {st.step} mb {m}: {have()}/{K} in")
@@ -809,14 +820,14 @@ class Executor:
         deadline_used = None
         while have() < K:
             if m not in st.first_t:
-                self._pump(None)  # the first cut opens the window
+                self._pump(st.step, None)  # the first cut opens the window
                 continue
             d = self.static_deadline_s
             if d is None:
                 d = self.deadline.deadline_s()
             if d is None:
                 # bootstrap barrier: no estimate yet, wait for everyone
-                if not self._pump(None):
+                if not self._pump(st.step, None):
                     raise self._idle_error(
                         "awaiting cuts at the bootstrap barrier",
                         f"step {st.step} mb {m}: {have()}/{K} in")
@@ -828,12 +839,12 @@ class Executor:
                 # DELIVERED while role 0 was busy on an earlier microbatch
                 # beat the deadline and must not be counted as a miss (the
                 # drain timestamp, not the true arrival, is all we see)
-                while have() < K and self._pump(0.0):
+                while have() < K and self._pump(st.step, 0.0):
                     pass
                 if have() < K:
                     break
                 continue
-            self._pump(remaining)
+            self._pump(st.step, remaining)
         if (self.deadline is not None and self.deadline.initial_s is None
                 and have() == K):
             # seed the adaptive controller from the first full barrier
@@ -841,33 +852,21 @@ class Executor:
         arrived = st.cuts.get(m, {})
         return [1.0 if k in arrived else 0.0 for k in range(K)], deadline_used
 
-    def _build_report(self, elapsed_s, live_matrix, misses, ledger, cuts,
+    def _build_report(self, elapsed_s, live_matrix, misses, ledger,
                       deadline_s, staleness) -> ExecReport:
-        """``cuts`` is the last microbatch's cut set — a (K, ...) stack for
-        uniform merges, a per-client list for ``merge_fn`` programs."""
         K = self.transport.num_clients
         if self.merge_fn is not None:
             # non-uniform program merge (e.g. vlm seq-concat): cuts differ
-            # in shape per client, so the per-client figures are means, and
-            # the collective model is the all-gather the program merge
-            # implies (the server needs every client's segment), not the
-            # reduction named by cfg.vertical.merge (which never executes)
-            per_mb_elements = int(round(
-                sum(int(c.size) for c in cuts) / K))
-            strategy = "concat"
+            # in shape per client, so the per-client figure is a mean
             cut_bytes = int(round(sum(
                 ledger.bytes_with_tag(f"cut[{k}]") for k in range(K)) / K))
-            itemsize = cuts[0].dtype.itemsize
         else:
-            per_mb_elements = int(cuts[0].size)
-            strategy = self.merge
             # the uplink tag is masked_cut[0] under secure aggregation
             cut_bytes = ledger.bytes_with_tag(self._schedule.cuts[0].tag)
             if self.agg_tree is not None:
                 # tree_cut[0] is shared by every top-level edge: divide out
                 # for the same per-client per-step figure the star reports
                 cut_bytes //= len(self.agg_tree.top_level)
-            itemsize = cuts.dtype.itemsize
         return ExecReport(
             mode=self.mode,
             transport=type(self.transport).__name__,
@@ -876,9 +875,6 @@ class Executor:
             live=live_matrix,
             misses_per_client=misses,
             cut_bytes_per_client=cut_bytes,
-            collective_bytes_per_client=self.microbatches
-            * collective_bytes_per_merge(
-                strategy, per_mb_elements, K, itemsize),
             deadline_s=deadline_s,
             staleness=staleness,
             tower_platform=self.transport.tower_platform,

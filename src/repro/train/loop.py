@@ -409,8 +409,10 @@ def train_split(
                          f"protocol aux slot ({aux_bytes} B in ledger)")
         if opt_state is None:
             opt_state = opt.init(server_params)
-        server_params, opt_state = opt.update(
-            server_params, res.server_grads, opt_state)
+        with jax.profiler.TraceAnnotation("train.server_update",
+                                          step=res.step):
+            server_params, opt_state = opt.update(
+                server_params, res.server_grads, opt_state)
         ema_state = res.ema_state
         report = res.report
         loss = float(res.loss)

@@ -254,47 +254,52 @@ class TowerWorker:
         if self.forward_delay_s > 0.0:
             time.sleep(self.forward_delay_s)
         step, mb = request["step"], request["mb"]
-        feats = request.get("feats")
-        if feats is None:
-            if self.feature_fn is None:
-                raise ValueError(
-                    f"client {self.client_id}: no feats in request and no "
-                    "feature_fn configured")
-            feats = self.feature_fn(step, mb)
-        feats = jnp.asarray(feats)
-        self._feats[(step, mb)] = feats
-        params = self._step_params.setdefault(step, self.params)
-        cut = self.tower_fwd(params, feats)
-        if self._secure is not None:
-            # mask at the source: role 0 only ever observes the blinded cut.
-            # round_idx is unique per (step, mb) at any driver window W, so
-            # masks are never reused across uplinks (differencing two steps'
-            # masked cuts yields noise, not the raw activation delta).  The
-            # worker — not role 0 — enforces freshness: requests arrive FIFO
-            # in (step, mb) order, so a non-increasing round means a replayed
-            # or recycled step id, and sending a reused mask would let the
-            # server difference two uplinks to the raw activation delta
-            sec = self._secure
-            round_idx = step * sec["microbatches"] + mb
-            if round_idx <= sec["last_round"]:
-                raise ValueError(
-                    f"client {self.client_id}: mask round {round_idx} "
-                    f"(step {step}, mb {mb}) already used (last "
-                    f"{sec['last_round']}) — reusing a mask round leaks the "
-                    "raw activation delta; drive secure steps with strictly "
-                    "increasing step ids")
-            sec["last_round"] = round_idx
-            cut = secure_agg.mask_payload_with_keys(
-                cut, sec["pair_keys"], self.client_id, round_idx,
-                sec["scale"])
-        if self.compress is not None:
-            # compress at the source with error feedback: fold in what the
-            # previous step's encode dropped for this stream position, ship
-            # the lossy payload, carry the new leftover.  FIFO delivery
-            # makes the per-mb carry step-sequential at any driver window W
-            cut, self._ef_residual[mb] = comp_lib.compress_with_feedback(
-                cut, self._ef_residual.get(mb), self.compress,
-                self.topk_fraction)
+        with jax.profiler.TraceAnnotation("tower.forward", step=step, mb=mb,
+                                          client=self.client_id):
+            feats = request.get("feats")
+            if feats is None:
+                if self.feature_fn is None:
+                    raise ValueError(
+                        f"client {self.client_id}: no feats in request and no "
+                        "feature_fn configured")
+                feats = self.feature_fn(step, mb)
+            feats = jnp.asarray(feats)
+            self._feats[(step, mb)] = feats
+            params = self._step_params.setdefault(step, self.params)
+            cut = self.tower_fwd(params, feats)
+            if self._secure is not None:
+                # mask at the source: role 0 only ever observes the blinded
+                # cut.  round_idx is unique per (step, mb) at any driver
+                # window W, so masks are never reused across uplinks
+                # (differencing two steps' masked cuts yields noise, not the
+                # raw activation delta).  The worker — not role 0 — enforces
+                # freshness: requests arrive FIFO in (step, mb) order, so a
+                # non-increasing round means a replayed or recycled step id,
+                # and sending a reused mask would let the server difference
+                # two uplinks to the raw activation delta
+                sec = self._secure
+                round_idx = step * sec["microbatches"] + mb
+                if round_idx <= sec["last_round"]:
+                    raise ValueError(
+                        f"client {self.client_id}: mask round {round_idx} "
+                        f"(step {step}, mb {mb}) already used (last "
+                        f"{sec['last_round']}) — reusing a mask round leaks "
+                        "the raw activation delta; drive secure steps with "
+                        "strictly increasing step ids")
+                sec["last_round"] = round_idx
+                cut = secure_agg.mask_payload_with_keys(
+                    cut, sec["pair_keys"], self.client_id, round_idx,
+                    sec["scale"])
+            if self.compress is not None:
+                # compress at the source with error feedback: fold in what
+                # the previous step's encode dropped for this stream
+                # position, ship the lossy payload, carry the new leftover.
+                # FIFO delivery makes the per-mb carry step-sequential at
+                # any driver window W
+                cut, self._ef_residual[mb] = \
+                    comp_lib.compress_with_feedback(
+                        cut, self._ef_residual.get(mb), self.compress,
+                        self.topk_fraction)
         if self._relay_children:
             # relay: this cut is one part of the subtree partial sum; the
             # combined frame is emitted once every child's frame landed too
@@ -376,10 +381,14 @@ class TowerWorker:
                 jac.astype(jnp.float32),
             )
 
-        grad = jax.grad(tower_obj)(base)
-        prev = self._grad_sums.get(step)
-        self._grad_sums[step] = grad if prev is None else \
-            jax.tree_util.tree_map(jnp.add, prev, grad)
+        # the span ends before a deferred finish below runs the update,
+        # which has its own span
+        with jax.profiler.TraceAnnotation("tower.backward", step=step, mb=mb,
+                                          client=self.client_id):
+            grad = jax.grad(tower_obj)(base)
+            prev = self._grad_sums.get(step)
+            self._grad_sums[step] = grad if prev is None else \
+                jax.tree_util.tree_map(jnp.add, prev, grad)
         self._jacs_seen[step] = self._jacs_seen.get(step, 0) + 1
         pending = self._pending_finish.get(step)
         if pending is not None and \
@@ -421,8 +430,10 @@ class TowerWorker:
         else:
             avg = jax.tree_util.tree_map(lambda g: g / M, grad_sum)
         if self.optimizer is not None:
-            self.params, self.opt_state = self.optimizer.update(
-                self.params, avg, self.opt_state)
+            with jax.profiler.TraceAnnotation("tower.update", step=step,
+                                              client=self.client_id):
+                self.params, self.opt_state = self.optimizer.update(
+                    self.params, avg, self.opt_state)
         self._step_params.pop(step, None)
         self._jacs_seen.pop(step, None)
         # only THIS step's leftovers (no-wait misses); later steps' feats
