@@ -56,9 +56,24 @@ class TowerWorker:
 
     ``tower_fwd(params, feats) -> cut``; the backward objective is the same
     f32 vdot as ``protocol_step`` so gradients agree bit-for-bit with the
-    serial path.  ``feature_fn(step, mb) -> feats`` lets the worker own its
-    data (multiproc children regenerate slices from the shared seed);
-    requests may instead carry ``feats`` inline (sim/inproc wrappers).
+    serial path.
+
+    The per-step work runs as three compiled programs, built once in
+    ``__init__`` and cached by ``jax.jit`` per input shape and dtype: the
+    forward (``tower_fwd``), the backward (``(params, feats, jac) ->
+    grad``, the recomputed forward and its vjp, with ``feats`` and ``jac``
+    as arguments so that no step's data becomes a program constant) and
+    the local optimizer update.  Every family, transport and microbatch
+    shape goes through the same three; a new shape (an uneven last
+    microbatch, another family's tower) compiles once more.  ``traces``
+    counts how often each was traced.  No buffer is donated: the
+    ``_step_params`` snapshot a step's forwards ran under must outlive the
+    update that replaces ``self.params`` while later steps are in flight
+    (W > 1), and a donated update would free the arrays behind it.
+
+    ``feature_fn(step, mb) -> feats`` lets the worker own its data
+    (multiproc children regenerate slices from the shared seed); requests
+    may instead carry ``feats`` inline (sim/inproc wrappers).
     ``optimizer`` (repro.optim-style ``init``/``update``) enables local
     parameter updates at ``finish_step`` — the real split-learning flow,
     where tower params never leave the client.  ``forward_delay_s``
@@ -140,7 +155,6 @@ class TowerWorker:
                  topk_fraction: float = 0.25,
                  serve_fns=None):
         self.client_id = client_id
-        self.tower_fwd = tower_fwd
         self.params = tower_params
         self.feature_fn = feature_fn
         self.optimizer = optimizer
@@ -153,6 +167,17 @@ class TowerWorker:
         self.topk_fraction = topk_fraction
         self.serve_fns = serve_fns  # TowerServeFns when the family serves
         self.opt_state = optimizer.init(tower_params) if optimizer else None
+        self._traces = {"forward": 0, "backward": 0, "update": 0}
+        self._tower_fwd = self._compiled("forward", tower_fwd)
+
+        def tower_grad(params, feats, jac):
+            return jax.grad(lambda tp: jnp.vdot(
+                tower_fwd(tp, feats).astype(jnp.float32),
+                jac.astype(jnp.float32)))(params)
+
+        self._tower_grad = self._compiled("backward", tower_grad)
+        self._update = (self._compiled("update", optimizer.update)
+                        if optimizer else None)
         self._feats: dict = {}  # (step, mb) -> feats awaiting backward
         self._step_params: dict = {}  # step -> params its forwards ran under
         self._grad_sums: dict = {}  # step -> accumulated tower grads
@@ -164,6 +189,20 @@ class TowerWorker:
         self._relay_children: tuple = ()  # child ids when acting as a relay
         self._relay_parts: dict = {}  # (step, mb) -> {"self"|child_id: cut}
         self._serve_sessions: dict = {}  # request id -> tower KV session
+
+    def _compiled(self, name: str, fn: Callable) -> Callable:
+        """``jax.jit(fn)``, counting in ``traces[name]`` each time jax
+        traces it (the Python body runs only then)."""
+        def traced(*args):
+            self._traces[name] += 1
+            return fn(*args)
+        return jax.jit(traced)
+
+    @property
+    def traces(self) -> dict:
+        """How many times each compiled program was traced: one per input
+        shape that reached it."""
+        return dict(self._traces)
 
     # -- ops ----------------------------------------------------------------
 
@@ -266,7 +305,7 @@ class TowerWorker:
             feats = jnp.asarray(feats)
             self._feats[(step, mb)] = feats
             params = self._step_params.setdefault(step, self.params)
-            cut = self.tower_fwd(params, feats)
+            cut = self._tower_fwd(params, feats)
             if self._secure is not None:
                 # mask at the source: role 0 only ever observes the blinded
                 # cut.  round_idx is unique per (step, mb) at any driver
@@ -374,18 +413,11 @@ class TowerWorker:
         # server's jacobian is w.r.t. THAT cut, and at W > 1 a later step's
         # finish may already have moved self.params past the snapshot
         base = self._step_params.get(step, self.params)
-
-        def tower_obj(tp):
-            return jnp.vdot(
-                self.tower_fwd(tp, feats).astype(jnp.float32),
-                jac.astype(jnp.float32),
-            )
-
         # the span ends before a deferred finish below runs the update,
         # which has its own span
         with jax.profiler.TraceAnnotation("tower.backward", step=step, mb=mb,
                                           client=self.client_id):
-            grad = jax.grad(tower_obj)(base)
+            grad = self._tower_grad(base, feats, jac)
             prev = self._grad_sums.get(step)
             self._grad_sums[step] = grad if prev is None else \
                 jax.tree_util.tree_map(jnp.add, prev, grad)
@@ -432,7 +464,7 @@ class TowerWorker:
         if self.optimizer is not None:
             with jax.profiler.TraceAnnotation("tower.update", step=step,
                                               client=self.client_id):
-                self.params, self.opt_state = self.optimizer.update(
+                self.params, self.opt_state = self._update(
                     self.params, avg, self.opt_state)
         self._step_params.pop(step, None)
         self._jacs_seen.pop(step, None)

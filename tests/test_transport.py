@@ -50,6 +50,21 @@ def _assert_trees_close(a, b, atol=1e-5):
         np.testing.assert_allclose(la, lb, atol=atol, rtol=1e-4)
 
 
+def _precompile(workers, feats, rows):
+    """Compile each worker's forward and backward at the ``rows``-row
+    microbatch shape before a wall-clock test, so that the injected sleeps
+    and not the first call's compile set when the cuts arrive."""
+    for w, f in zip(workers, feats):
+        delay, w.forward_delay_s = w.forward_delay_s, 0.0
+        cut = w.handle({"op": "forward", "step": -1, "mb": 0,
+                        "feats": f[:rows]})["cut"]
+        w.handle({"op": "backward", "step": -1, "mb": 0,
+                  "jac": jnp.zeros_like(cut)})
+        w.handle({"op": "finish_step", "step": -1, "microbatches": 1,
+                  "collect": False})
+        w.forward_delay_s = delay
+
+
 # ---------------------------------------------------------------------------
 # inproc (threads): staleness-0 identity with the serial path
 # ---------------------------------------------------------------------------
@@ -132,6 +147,7 @@ def test_inproc_nowait_wallclock_straggler():
                     forward_delay_s=delay if k == 1 else 0.0)
         for k in range(cfg.num_clients)
     ]
+    _precompile(workers, feats, 8)
     with InprocTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
                             mode="nowait", microbatches=2, deadline=0.15)
@@ -169,6 +185,7 @@ def test_inproc_nowait_busy_server_does_not_fabricate_misses():
                     forward_delay_s=0.05 if k == 1 else 0.0)
         for k in range(cfg.num_clients)
     ]
+    _precompile(workers, feats, 8)
     with InprocTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, slow_loss, cfg.merge,
                             mode="nowait", microbatches=2, deadline=0.3)
@@ -342,6 +359,65 @@ def test_worker_defers_finish_until_jacobians_land():
     _assert_trees_close(resp["grad"], g0, atol=1e-6)
 
 
+@pytest.mark.parametrize("case", ["steady", "new_shape", "matches_eager"])
+def test_worker_compiles_each_program_once_per_shape(case):
+    """The worker's forward, backward and local AdamW update are compiled
+    programs: each is traced once per input shape and reused every step
+    after, and what they compute is what an eager ``jax.grad`` of the same
+    f32 vdot objective and an eager update give."""
+    from repro.optim import AdamW
+
+    cfg = TINY
+    params, feats, y, loss_fn = _setup(cfg)
+    opt = AdamW(learning_rate=1e-2, weight_decay=0.1, grad_clip_norm=1.0)
+    once = {"forward": 1, "backward": 1, "update": 1}
+
+    if case == "matches_eager":
+        p = params["towers"][0]
+        worker = TowerWorker(0, towers.mlp_tower_apply, p, optimizer=opt)
+        state = opt.init(p)
+        for step in range(2):
+            ks = jax.random.split(jax.random.PRNGKey(10 + step), 2)
+            f = jax.random.normal(ks[0], (16, 8))
+            jac = jax.random.normal(ks[1], (16, cfg.cut_dim))
+            cut = worker.handle({"op": "forward", "step": step, "mb": 0,
+                                 "feats": f})["cut"]
+            worker.handle({"op": "backward", "step": step, "mb": 0,
+                           "jac": jac})
+            done = worker.handle({"op": "finish_step", "step": step,
+                                  "microbatches": 1, "collect": True})
+            grad = jax.grad(lambda tp: jnp.vdot(
+                towers.mlp_tower_apply(tp, f).astype(jnp.float32),
+                jac.astype(jnp.float32)))(p)
+            want = (towers.mlp_tower_apply(p, f), grad)
+            p, state = opt.update(p, grad, state)
+            for got, ref in zip(jax.tree_util.tree_leaves(
+                    (cut, done["grad"], worker.params)),
+                    jax.tree_util.tree_leaves(want + (p,))):
+                np.testing.assert_allclose(got, ref, rtol=0, atol=1e-6)
+        assert worker.traces == once
+        return
+
+    workers = [TowerWorker(k, towers.mlp_tower_apply, params["towers"][k],
+                           optimizer=opt)
+               for k in range(cfg.num_clients)]
+    with InprocTransport(workers) as tr:
+        executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
+                            mode="pipelined", microbatches=1)
+        for step in range(4):
+            executor.run_step(params["server"], y, step=step,
+                              features=feats, collect_grads=False)
+            assert [w.traces for w in workers] == [once] * cfg.num_clients
+        if case == "new_shape":
+            # half the rows: forward and backward see a new shape, the
+            # update's params and gradients do not
+            executor.run_step(params["server"], y[:8], step=4,
+                              features=[f[:8] for f in feats],
+                              collect_grads=False)
+            assert [w.traces for w in workers] == [
+                {"forward": 2, "backward": 2, "update": 1}] * cfg.num_clients
+
+
 # ---------------------------------------------------------------------------
 # adaptive deadline controller
 # ---------------------------------------------------------------------------
@@ -396,13 +472,12 @@ def test_nowait_busy_server_clamps_deadline_observations():
     # (floor_frac * initial = 0.175s), since the healthy cluster's small
     # spreads tighten the adaptive window there immediately
     delays = [0.0, 0.05, 0.1]
-    for k in range(cfg.num_clients):  # pre-trace so sleeps dominate timing
-        towers.mlp_tower_apply(params["towers"][k], feats[k][:8])
     workers = [
         TowerWorker(k, towers.mlp_tower_apply, params["towers"][k],
                     forward_delay_s=delays[k])
         for k in range(cfg.num_clients)
     ]
+    _precompile(workers, feats, 8)  # so sleeps dominate timing
     ctl = AdaptiveDeadline(cfg.num_clients, initial_s=0.35)
     with InprocTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, slow_loss, cfg.merge,
@@ -425,13 +500,12 @@ def test_nowait_recovered_straggler_rejoins_merges():
     cfg = TINY3
     params, feats, y, loss_fn = _setup(cfg)
     delay = 0.8
-    for k in range(cfg.num_clients):  # pre-trace so sleeps dominate timing
-        towers.mlp_tower_apply(params["towers"][k], feats[k])
     workers = [
         TowerWorker(k, towers.mlp_tower_apply, params["towers"][k],
                     forward_delay_s=delay if k == 2 else 0.0)
         for k in range(cfg.num_clients)
     ]
+    _precompile(workers, feats, len(y))  # so sleeps dominate timing
     ctl = AdaptiveDeadline(cfg.num_clients, initial_s=0.2, decay=0.3)
     with InprocTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
