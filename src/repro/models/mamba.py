@@ -58,12 +58,39 @@ def _causal_conv(u, w, b):
 
 def _segsum_exp(a):
     """a: (..., Q) log-decays -> L: (..., Q, Q) with L[i,j]=exp(sum_{j<t<=i} a_t),
-    lower-triangular (i >= j), zero elsewhere."""
+    lower-triangular (i >= j), zero elsewhere.
+
+    The exponent is masked to -inf before the ``exp``: above the diagonal
+    ``cum_i - cum_j`` is a sum of NEGATED decays, which overflows ``exp``
+    over a long chunk, and the backward would then multiply its zero
+    cotangent by inf."""
     Q = a.shape[-1]
     cum = jnp.cumsum(a, axis=-1)
     diff = cum[..., :, None] - cum[..., None, :]  # (..., i, j) = sum_{j<t<=i}
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    return jnp.where(mask, jnp.exp(diff), 0.0)
+    return jnp.exp(jnp.where(mask, diff, -jnp.inf))
+
+
+_SSD_TRACES = [0]
+
+
+def ssd_traces() -> int:
+    """How many times ``ssd_chunked`` was traced (its Python body runs only
+    then): once per input shape under ``jax.jit``, once per call in an
+    eager caller such as role 0's server step."""
+    return _SSD_TRACES[0]
+
+
+def gated_rmsnorm(params, y, z, groups: int, eps: float = 1e-5):
+    """Mamba-2's gated RMSNorm (``norm_before_gate=False``):
+    ``rmsnorm(y * silu(z))``, normalized over each group of
+    ``d_inner / groups`` columns, in f32."""
+    g = (y * jax.nn.silu(z)).astype(jnp.float32)
+    lead, d = g.shape[:-1], g.shape[-1]
+    g = g.reshape(*lead, groups, d // groups)
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True) + eps)
+    g = g.reshape(*lead, d) * params["scale"].astype(jnp.float32)
+    return g.astype(y.dtype)
 
 
 def ssd_chunked(x, dt, A, Bmat, Cmat, chunk: int, initial_state=None):
@@ -74,7 +101,13 @@ def ssd_chunked(x, dt, A, Bmat, Cmat, chunk: int, initial_state=None):
     A: (H,) — negative decay rates
     Bmat/Cmat: (B, S, G, N) — input/output projections (G groups, GQA-style)
     Returns (y: (B, S, H, P), final_state: (B, H, P, N)).
+
+    Each chunk step is recomputed in the backward pass (``jax.checkpoint``):
+    only the (B, H, P, N) state between chunks is saved, not the chunk's
+    (Q, Q) decay and score matrices.  On the device the scan is one
+    ``while`` loop that carries the state.
     """
+    _SSD_TRACES[0] += 1
     Bsz, S, H, P = x.shape
     G, N = Bmat.shape[2], Bmat.shape[3]
     rep = H // G
@@ -94,6 +127,7 @@ def ssd_chunked(x, dt, A, Bmat, Cmat, chunk: int, initial_state=None):
     if initial_state is None:
         initial_state = jnp.zeros((Bsz, H, P, N), jnp.float32)
 
+    @jax.checkpoint
     def chunk_step(state, inputs):
         a_q, x_q, B_q, C_q = inputs
         cum = jnp.cumsum(a_q, axis=1)
@@ -142,7 +176,7 @@ def mamba_apply(params, x, cfg: SSMConfig, d_model: int):
     y, state = ssd_chunked(xh, dt, A, Bm, Cm, cfg.chunk_size)
     y = y + params["D"][None, None, :, None] * xh
     y = y.reshape(Bsz, S, d_inner)
-    y = layers.rmsnorm(params["norm"], y) * jax.nn.silu(z)
+    y = gated_rmsnorm(params["norm"], y, z, G)
     out = (y @ params["out_proj"]).astype(x.dtype)
     conv_tail = jnp.concatenate([xs, Bm.reshape(Bsz, S, G * N), Cm.reshape(Bsz, S, G * N)], axis=-1)[:, -(W - 1):, :]
     return out, state, conv_tail
@@ -181,6 +215,6 @@ def mamba_decode_step(params, x, ssm_state, conv_state, cfg: SSMConfig, d_model:
     y = jnp.einsum("bhn,bhpn->bhp", C_rep, new_state)  # (B,H,P)
     y = y + params["D"][None, :, None] * xh
     y = y.reshape(Bsz, d_inner).astype(x.dtype)
-    y = layers.rmsnorm(params["norm"], y) * jax.nn.silu(z)
+    y = gated_rmsnorm(params["norm"], y, z, G)
     out = (y @ params["out_proj"]).astype(x.dtype)[:, None, :]
     return out, new_state, window[:, 1:, :]

@@ -19,6 +19,7 @@ cross-client communication below the cut by construction.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional
 
@@ -390,15 +391,31 @@ def moe_stack_decode(stacked, x, cache_k, cache_v, index, kv_positions,
     return x, nk, nv, npos[0]
 
 
+@functools.lru_cache(maxsize=None)
+def _mamba_stack_program(ssm_cfg: SSMConfig, d_model: int, eps: float, remat):
+    """The scan over a stack of Mamba blocks, compiled once per
+    configuration and input shape."""
+    def mamba_stack(stacked, x):
+        def body(h, lp):
+            h, _, _ = mamba_block_apply(lp, h, ssm_cfg, d_model, eps)
+            return h, None
+
+        x, _ = jax.lax.scan(_maybe_checkpoint(body, remat), x, stacked)
+        return x
+
+    return jax.jit(mamba_stack)
+
+
 def mamba_stack_apply(stacked, x, ssm_cfg: SSMConfig, d_model: int, eps: float,
                       remat=False):
-    def body(h, lp):
-        h, _, _ = mamba_block_apply(lp, h, ssm_cfg, d_model, eps)
-        return h, None
-
-    body = _maybe_checkpoint(body, remat)
-    x, _ = jax.lax.scan(body, x, stacked)
-    return x
+    """A stack of Mamba blocks as one compiled program.  An eager caller,
+    such as role 0's server step, dispatches it and its backward without
+    tracing the layers' chunked SSD scans again (``mamba.ssd_traces``);
+    the span ``ssd.scan`` covers that dispatch."""
+    program = _mamba_stack_program(ssm_cfg, d_model, eps, remat)
+    with jax.profiler.TraceAnnotation("ssd.scan", seq=x.shape[1],
+                                      width=d_model):
+        return program(stacked, x)
 
 
 def mamba_stack_decode(stacked, x, ssm_states, conv_states, ssm_cfg: SSMConfig,

@@ -7,6 +7,7 @@ sharded over the data axis.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -37,6 +38,18 @@ class AdamW:
         return jnp.asarray(self.learning_rate, jnp.float32)
 
     def update(self, params, grads, state):
+        """One step: clipping, moments, bias correction and decoupled decay
+        as one program, compiled once per parameter tree.  An eager caller
+        (role 0's server update) then makes one fused pass over each leaf,
+        where op-by-op dispatch would leave several full-size temporaries
+        of each leaf allocated for as long as the device lags the host."""
+        return self._compiled_update(params, grads, state)
+
+    @functools.cached_property
+    def _compiled_update(self):
+        return jax.jit(self._update)
+
+    def _update(self, params, grads, state):
         count = state["count"] + 1
         if self.grad_clip_norm is not None:
             from repro.optim.clipping import clip_by_global_norm

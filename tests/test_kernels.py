@@ -288,11 +288,24 @@ def test_ssd_kernel_matches_chunked_model_hypothesis_sweep():
     prop()
 
 
-def test_ssd_chunked_matches_sequential_recurrence():
+def _large_decay_inputs(B, S, H, P, N, seed=0):
+    """Log-decays |dt * A| of 2-6 a step: over a chunk of 32 they sum past
+    100, where ``exp`` of the negated sums above the diagonal overflows."""
+    x, dt, A, Bm, Cm = _ssd_inputs(B, S, H, P, N, seed)
+    dt = 1.0 + jax.random.uniform(jax.random.PRNGKey(seed + 1), dt.shape)
+    A = -2.0 - jax.random.uniform(jax.random.PRNGKey(seed + 2), A.shape)
+    return x, dt, A, Bm, Cm
+
+
+@pytest.mark.parametrize("S,chunk,inputs", [
+    (32, 8, _ssd_inputs),
+    (64, 32, _large_decay_inputs),
+], ids=["moderate_decay", "large_decay"])
+def test_ssd_chunked_matches_sequential_recurrence(S, chunk, inputs):
     """Ground truth: the exact step-by-step SSM recurrence."""
-    B, S, H, P, N = 1, 32, 2, 8, 4
-    x, dt, A, Bm, Cm = _ssd_inputs(B, S, H, P, N, seed=3)
-    y_chunk, state_chunk = mamba_lib.ssd_chunked(x, dt, A, Bm, Cm, chunk=8)
+    B, H, P, N = 1, 2, 8, 4
+    x, dt, A, Bm, Cm = inputs(B, S, H, P, N, seed=3)
+    y_chunk, state_chunk = mamba_lib.ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
 
     state = jnp.zeros((B, H, P, N))
     ys = []
