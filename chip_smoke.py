@@ -36,9 +36,8 @@ K, ROWS, D_MODEL = 4, 2048, 960
 # f32 K=4 reductions in a different association (and a divide that may be a
 # reciprocal multiply) differ from the oracle by a few ulp of values ~O(4)
 MERGE_RTOL = MERGE_ATOL = 1e-5
-# batch 8 does not fit: the eager server step (value_and_grad outside jit)
-# keeps its scan residuals for all 30 server layers and ran out of the 16 GB
-# of HBM on its first step on a v5e
+# the benchmark cell's shape; batch 8 x 256 also fits on a v5e now that role
+# 0's server step is one compiled program (eagerly, it ran out of HBM)
 BATCH, SEQ, STEPS = 4, 256, 3
 
 

@@ -77,7 +77,7 @@ _SSD_TRACES = [0]
 def ssd_traces() -> int:
     """How many times ``ssd_chunked`` was traced (its Python body runs only
     then): once per input shape under ``jax.jit``, once per call in an
-    eager caller such as role 0's server step."""
+    eager caller."""
     return _SSD_TRACES[0]
 
 

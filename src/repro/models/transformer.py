@@ -408,10 +408,11 @@ def _mamba_stack_program(ssm_cfg: SSMConfig, d_model: int, eps: float, remat):
 
 def mamba_stack_apply(stacked, x, ssm_cfg: SSMConfig, d_model: int, eps: float,
                       remat=False):
-    """A stack of Mamba blocks as one compiled program.  An eager caller,
-    such as role 0's server step, dispatches it and its backward without
-    tracing the layers' chunked SSD scans again (``mamba.ssd_traces``);
-    the span ``ssd.scan`` covers that dispatch."""
+    """A stack of Mamba blocks as one compiled program.  An eager caller
+    dispatches it and its backward without tracing the layers' chunked SSD
+    scans again (``mamba.ssd_traces``); inside a compiled caller, such as
+    role 0's server step, it is part of that program.  The span
+    ``ssd.scan`` covers the dispatch, or the tracing in a compiled caller."""
     program = _mamba_stack_program(ssm_cfg, d_model, eps, remat)
     with jax.profiler.TraceAnnotation("ssd.scan", seq=x.shape[1],
                                       width=d_model):

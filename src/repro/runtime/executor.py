@@ -66,6 +66,7 @@ from repro.core import straggler as straggler_lib
 from repro.core.protocol import Ledger, step_schedule
 from repro.core.secure_agg import KEYX_GROUP_BYTES
 from repro.runtime.deadline import AdaptiveDeadline
+from repro.transport.base import counted_jit
 from repro.transport.tree import TreeRouter
 
 DROP_POLICIES = ("neutral", "fused", "impute")
@@ -98,6 +99,13 @@ def tree_mean(trees):
     return jax.tree_util.tree_map(
         lambda *leaves: sum(leaves) / len(leaves), *trees
     )
+
+
+def _signature(*args) -> tuple:
+    """The tree structure, shapes and dtypes of ``args``: what a compiled
+    program's cache keys on."""
+    leaves, treedef = jax.tree_util.tree_flatten(args)
+    return treedef, tuple((a.shape, a.dtype) for a in leaves)
 
 
 @dataclass
@@ -229,6 +237,19 @@ class Executor:
     ``merge_fn``, compression (codec frames cannot be partial-summed), and
     any non-barrier execution (a dropped client inside a combined frame
     cannot be masked out after the fact).
+
+    Role 0's server work is two compiled programs, each traced once per
+    input shape (:attr:`traces`): ``server_step``, the loss of the server
+    forward and its gradients with respect to the server params and the
+    merged cut, and ``mean``, the mean over M > 1 microbatches.  The
+    merge stays outside them, run eagerly under ``jax.vjp`` (the
+    ``merge_pool`` kernel's own forward and backward programs, the EMA
+    imputation, the tree's final sum, a program ``merge_fn``), and the
+    server program's gradient for the merged cut flows back through that
+    vjp to the cut gradients.  The labels or batch context are arguments,
+    not constants of the program, and the logits never leave it: the
+    ledger's head records take their size from the trace.  Nothing is
+    donated: the caller updates the server params after the step.
     """
 
     def __init__(self, transport, server_fwd: Callable, loss_fn: Callable,
@@ -309,6 +330,33 @@ class Executor:
                                        tree=agg_tree)
         self._inflight: dict[int, _InflightStep] = {}  # insertion-ordered
         self._retired_first_t: dict[tuple[int, int], float] = {}
+        self._traces = {"server_step": 0, "mean": 0}
+        self._heads: dict = {}  # argument signature -> the logits' shape
+        self._server_step = counted_jit(
+            self._traces, "server_step", jax.value_and_grad(
+                self._server_loss, argnums=(0, 1), has_aux=True))
+        self._mean = counted_jit(self._traces, "mean", tree_mean)
+
+    @property
+    def traces(self) -> dict:
+        """How many times each compiled program was traced: one per input
+        shape that reached it."""
+        return dict(self._traces)
+
+    def _server_loss(self, server_params, merged, batch):
+        """The server forward's loss plus its aux term; returns (loss,
+        aux).  Records the logits' shape for the argument signature."""
+        if self.server_takes_batch:
+            out = self.server_fwd(server_params, merged, batch)
+        else:
+            out = self.server_fwd(server_params, merged)
+        if self.server_aux:
+            logits, aux = out
+        else:
+            logits, aux = out, jnp.zeros((), jnp.float32)
+        self._heads[_signature(server_params, merged, batch)] = \
+            jax.ShapeDtypeStruct(logits.shape, logits.dtype)
+        return self.loss_fn(logits, batch) + aux, aux
 
     def _idle_error(self, phase: str, detail: str = "") -> RuntimeError:
         """Uniform phrasing for every wait loop that drains the shared
@@ -509,7 +557,7 @@ class Executor:
         staleness = sum(1 for s in self._inflight if s > st.step)
         mbsz = st.mbsz
 
-        losses, aux_acc, server_grad_acc, live_matrix = [], [], [], []
+        outs, live_matrix = [], []
         misses = [0] * K
         last_deadline: Optional[float] = self.static_deadline_s
 
@@ -554,51 +602,40 @@ class Executor:
                 lambda a: a[m * mbsz:(m + 1) * mbsz], st.labels)
             live_vec = jnp.asarray(live_row, jnp.float32)
 
-            def server_loss(server_p, cuts):
+            def merge(cuts):
                 if self.agg_tree is not None:
                     # final merge over the top-level partial sums; avg is
                     # the full-tree sum over K (NOT over len(top_level))
-                    new_ema = ema_state
                     merged = fast_merge(cuts, "sum")
                     if self.merge == "avg":
                         merged = merged / K
-                elif self.merge_fn is not None:
-                    new_ema = ema_state
+                    return merged, ema_state
+                if self.merge_fn is not None:
                     mask = merge_mask if self.drop_policy == "neutral" else None
-                    merged = self.merge_fn(cuts, mask)
-                elif self.drop_policy == "impute":
+                    return self.merge_fn(cuts, mask), ema_state
+                if self.drop_policy == "impute":
                     imputed, new_ema = straggler_lib.impute_stack(
                         cuts, live_vec, ema_state, decay=self.ema_decay)
-                    merged = fast_merge(imputed, self.merge)
-                elif self.drop_policy == "neutral":
-                    new_ema = ema_state
-                    merged = merge_lib.merge_stacked(
-                        cuts, self.merge, live_mask=merge_mask)
-                else:
-                    new_ema = ema_state
-                    merged = fast_merge(cuts, self.merge)
-                if self.server_takes_batch:
-                    out = self.server_fwd(server_p, merged, labels_m)
-                else:
-                    out = self.server_fwd(server_p, merged)
-                if self.server_aux:
-                    logits, aux = out
-                else:
-                    logits, aux = out, jnp.zeros((), jnp.float32)
-                loss = self.loss_fn(logits, labels_m) + aux
-                return loss, (logits, aux, new_ema)
+                    return fast_merge(imputed, self.merge), new_ema
+                if self.drop_policy == "neutral":
+                    return merge_lib.merge_stacked(
+                        cuts, self.merge, live_mask=merge_mask), ema_state
+                return fast_merge(cuts, self.merge), ema_state
 
             with jax.profiler.TraceAnnotation(
                     "executor.server_step", step=st.step, mb=m):
-                (loss_m, (logits, aux_m, ema_state)), (sg, cut_grads) = \
-                    jax.value_and_grad(server_loss, argnums=(0, 1),
-                                       has_aux=True)(server_params, cuts_in)
-            st.ledger.record_spec(schedule.head_out, logits)
+                merged, merge_vjp, ema_state = jax.vjp(merge, cuts_in,
+                                                       has_aux=True)
+                (loss_m, aux_m), (sg, merged_grad) = self._server_step(
+                    server_params, merged, labels_m)
+                cut_grads, = merge_vjp(merged_grad)
+            # the logits' shape and dtype, from the program's trace
+            head = self._heads[_signature(server_params, merged, labels_m)]
+            st.ledger.record_spec(schedule.head_out, head)
             if self.server_aux:
                 # the aux scalar rides the role-0 -> role-3 loss exchange
                 st.ledger.record_spec(schedule.aux, aux_m)
-                aux_acc.append(aux_m)
-            st.ledger.record_spec(schedule.head_jac, logits)
+            st.ledger.record_spec(schedule.head_jac, head)
 
             # (client, jacobian) per backward, shipped once the span below
             # has closed: a transport that runs its workers on this thread
@@ -652,8 +689,7 @@ class Executor:
                     "op": "backward", "step": st.step, "mb": m,
                     "jac": jac_out,
                 })
-            losses.append(loss_m)
-            server_grad_acc.append(sg)
+            outs.append((loss_m, aux_m if self.server_aux else None, sg))
 
         for k in range(K):
             transport.submit(k, {
@@ -667,9 +703,8 @@ class Executor:
                     f"step {st.step}: {sum(st.done)}/{K} workers done")
         self._retire(st)
 
-        loss = sum(losses) / M
-        aux = sum(aux_acc) / M if aux_acc else None
-        server_grads = tree_mean(server_grad_acc)
+        # (loss, aux, server grads) over the microbatches; one needs no mean
+        loss, aux, server_grads = outs[0] if M == 1 else self._mean(outs)
         tower_grads = list(st.grads) if collect_grads else None
         if report is None:
             report = self._build_report(

@@ -21,6 +21,15 @@ from repro.core import secure_agg
 from repro.transport import ops as ops_registry
 
 
+def counted_jit(traces: dict, name: str, fn: Callable) -> Callable:
+    """``jax.jit(fn)``, counting in ``traces[name]`` each time jax traces
+    it (the Python body runs only then)."""
+    def traced(*args):
+        traces[name] += 1
+        return fn(*args)
+    return jax.jit(traced)
+
+
 class Transport:
     """Star-topology message plane; role 0 (the executor) is the caller."""
 
@@ -168,15 +177,15 @@ class TowerWorker:
         self.serve_fns = serve_fns  # TowerServeFns when the family serves
         self.opt_state = optimizer.init(tower_params) if optimizer else None
         self._traces = {"forward": 0, "backward": 0, "update": 0}
-        self._tower_fwd = self._compiled("forward", tower_fwd)
+        self._tower_fwd = counted_jit(self._traces, "forward", tower_fwd)
 
         def tower_grad(params, feats, jac):
             return jax.grad(lambda tp: jnp.vdot(
                 tower_fwd(tp, feats).astype(jnp.float32),
                 jac.astype(jnp.float32)))(params)
 
-        self._tower_grad = self._compiled("backward", tower_grad)
-        self._update = (self._compiled("update", optimizer.update)
+        self._tower_grad = counted_jit(self._traces, "backward", tower_grad)
+        self._update = (counted_jit(self._traces, "update", optimizer.update)
                         if optimizer else None)
         self._feats: dict = {}  # (step, mb) -> feats awaiting backward
         self._step_params: dict = {}  # step -> params its forwards ran under
@@ -189,14 +198,6 @@ class TowerWorker:
         self._relay_children: tuple = ()  # child ids when acting as a relay
         self._relay_parts: dict = {}  # (step, mb) -> {"self"|child_id: cut}
         self._serve_sessions: dict = {}  # request id -> tower KV session
-
-    def _compiled(self, name: str, fn: Callable) -> Callable:
-        """``jax.jit(fn)``, counting in ``traces[name]`` each time jax
-        traces it (the Python body runs only then)."""
-        def traced(*args):
-            self._traces[name] += 1
-            return fn(*args)
-        return jax.jit(traced)
 
     @property
     def traces(self) -> dict:

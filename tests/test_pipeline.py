@@ -35,6 +35,15 @@ FAMILY_ARCHS = [
 ]
 
 
+def _hold_role0(seconds):
+    """Injected role-0 compute inside a loss: the server step is compiled,
+    so a plain sleep would run once, when it is traced; an ordered host
+    callback sleeps on every execution, one microbatch after another."""
+    import time as _time
+
+    jax.debug.callback(lambda: _time.sleep(seconds), ordered=True)
+
+
 def _mlp_steps(cfg, n_steps, batch=8, seed=0):
     """Per-step features/labels streams for the tiny MLP."""
     slices = split_model.feature_slices(cfg)
@@ -248,7 +257,7 @@ def test_pipeline_w2_beats_w1_wallclock_and_sim_predicts_it():
     feats_by_step, y_by_step = _mlp_steps(cfg, S + 1)
 
     def slow_loss(logits, labels):
-        _time.sleep(server_delay)
+        _hold_role0(server_delay)
         return split_model.softmax_xent(logits, labels, cfg.num_classes)
 
     def run(window):
@@ -310,7 +319,7 @@ def test_pipeline_m2_w2_wallclock_band_matches_sim():
     feats_by_step, y_by_step = _mlp_steps(cfg, S + 1)
 
     def slow_loss(logits, labels):
-        _time.sleep(server_delay)  # per microbatch: role-0 merge+head work
+        _hold_role0(server_delay)  # per microbatch: role-0 merge+head work
         return split_model.softmax_xent(logits, labels, cfg.num_classes)
 
     def run(window):
