@@ -162,7 +162,7 @@ def bench_transport(out: dict) -> None:
             tr = make(workers)
             try:
                 executor = Executor(tr, towers.mlp_tower_apply, loss_fn,
-                                    cfg.merge, mode="pipelined",
+                                    cfg.merge,
                                     microbatches=4)
                 executor.run_step(params["server"], y, features=feats)  # warm
                 t0 = time.time()
@@ -225,7 +225,7 @@ def bench_split_exec(out: dict) -> None:
                        for k in range(program.num_clients)]
             with InprocTransport(workers) as tr:
                 executor = Executor(tr, program.server_fwd, program.loss_fn,
-                                    program.merge, mode="pipelined",
+                                    program.merge,
                                     microbatches=1, secure_agg=secure,
                                     compress=compress,
                                     **program.executor_kwargs)
@@ -358,7 +358,7 @@ def bench_split_pipeline(out: dict, *, full: bool = False) -> None:
                     [TowerWorker(k, program.tower_fwd(k), towers_p[k])
                      for k in range(program.num_clients)]),
                 lambda tr: Executor(tr, program.server_fwd, program.loss_fn,
-                                    program.merge, mode="pipelined",
+                                    program.merge,
                                     microbatches=1,
                                     **program.executor_kwargs),
                 lambda step: ctx, lambda step: feats,
@@ -422,7 +422,7 @@ def bench_split_pipeline(out: dict, *, full: bool = False) -> None:
             dt = run_windowed(
                 make,
                 lambda tr: Executor(tr, towers.mlp_tower_apply, slow_loss,
-                                    cfg.merge, mode="pipelined",
+                                    cfg.merge,
                                     microbatches=1),
                 lambda step: y, lambda step: None,
                 params["server"], W, ctl_steps)
@@ -492,7 +492,7 @@ def bench_tree_sweep(out: dict) -> None:
             ex = None
             try:
                 ex = Executor(tr, towers.mlp_tower_apply, loss_fn,
-                              cfg.merge, mode="pipelined", microbatches=M,
+                              cfg.merge, microbatches=M,
                               agg_tree=tree)
                 res = ex.run_step(params["server"], y, features=feats,
                                   collect_grads=False)  # warm / trace

@@ -64,7 +64,7 @@ def iter_src_files(root: Path, overrides: Optional[dict] = None,
 
 def _is_kindish(node: ast.AST) -> bool:
     """Does this expression plausibly hold a wire kind?  Conservative on
-    names (exact ``kind`` or ``*_kind``) so ``drop_policy``-style strings
+    names (exact ``kind`` or ``*_kind``) so ``mode``-style strings
     are never dragged into the kind namespace."""
     if isinstance(node, ast.Name):
         return node.id == "kind" or node.id.endswith("_kind")

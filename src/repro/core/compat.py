@@ -55,7 +55,6 @@ FEATURE_KWARGS = {
     "nowait": "nowait",
     "merge_fn": "merge_fn",
     "nonadditive": "merge",
-    "impute": "impute",
     "serve": "serve",
 }
 
@@ -67,7 +66,6 @@ CLI_NAMES = {
     "nowait": "--runtime nowait",
     "merge_fn": "a program merge_fn",
     "nonadditive": "a non-additive merge",
-    "impute": "--runtime nowait (EMA imputation)",
     "serve": "serving",
 }
 
@@ -93,13 +91,13 @@ RULES: tuple[CompatRule, ...] = (
     # program-shape rules come before the broad pairwise ones (mirrors the
     # historical raise order of the executor's constructor)
     CompatRule(
-        key="merge-fn-impute",
-        features=("merge_fn", "impute"),
+        key="merge-fn-nowait",
+        features=("merge_fn", "nowait"),
         layers=("executor",),
         reason=(
             "a program merge_fn (non-uniform cuts) cannot EMA-impute "
             "missing clients — there is no per-client frame to impute "
-            "into the concatenation; use a barrier mode "
+            "into the concatenation; use a barrier runtime "
             "(serial/pipelined)"),
     ),
     CompatRule(
@@ -127,10 +125,9 @@ RULES: tuple[CompatRule, ...] = (
         features=("secure", "nowait"),
         layers=("executor", "train", "launch"),
         reason=(
-            "secure aggregation requires barrier execution "
-            "(drop_policy='fused'): a client dropped in no-wait mode (or "
-            "recovered by any non-fused drop policy) leaves its pairwise "
-            "masks uncancelled and the aggregate unusable — there is no "
+            "secure aggregation requires barrier execution: a client "
+            "dropped in no-wait mode leaves its pairwise masks "
+            "uncancelled and the aggregate unusable — there is no "
             "dropout-recovery round"),
     ),
     CompatRule(
@@ -191,10 +188,10 @@ RULES: tuple[CompatRule, ...] = (
         features=("tree", "nowait"),
         layers=("engine", "executor", "train", "launch"),
         reason=(
-            "tree aggregation requires barrier execution "
-            "(drop_policy='fused'): a client folded into a relay's "
-            "combined frame has no per-client arrival to deadline, drop, "
-            "or EMA-impute at a no-wait merge"),
+            "tree aggregation requires barrier execution: a client "
+            "folded into a relay's combined frame has no per-client "
+            "arrival to deadline, drop, or EMA-impute at a no-wait "
+            "merge"),
     ),
     CompatRule(
         key="serve-secure",
@@ -240,7 +237,7 @@ class CompatError(ValueError):
 
 
 def active_features(*, secure=False, compress=None, tree=None, nowait=False,
-                    merge_fn=None, merge=None, impute=False,
+                    merge_fn=None, merge=None,
                     serve=False) -> dict[str, bool]:
     """Normalize heterogeneous caller flags (an AggTree object, a codec
     scheme string, a merge name, a callable) into the boolean feature set
@@ -252,13 +249,12 @@ def active_features(*, secure=False, compress=None, tree=None, nowait=False,
         "nowait": bool(nowait),
         "merge_fn": merge_fn is not None and merge_fn is not False,
         "nonadditive": merge is not None and merge not in ADDITIVE_MERGES,
-        "impute": bool(impute),
         "serve": bool(serve),
     }
 
 
 def check(layer: str, *, secure=False, compress=None, tree=None,
-          nowait=False, merge_fn=None, merge=None, impute=False,
+          nowait=False, merge_fn=None, merge=None,
           serve=False, context: str = "") -> None:
     """Reject the first matrix rule whose features are all active and
     which declares ``layer`` as an enforcement point.
@@ -272,7 +268,7 @@ def check(layer: str, *, secure=False, compress=None, tree=None,
                          f"(declared: {LAYERS})")
     active = active_features(
         secure=secure, compress=compress, tree=tree, nowait=nowait,
-        merge_fn=merge_fn, merge=merge, impute=impute, serve=serve)
+        merge_fn=merge_fn, merge=merge, serve=serve)
     for rule in RULES:
         if layer in rule.layers and all(active[f] for f in rule.features):
             raise CompatError(rule, layer, context)

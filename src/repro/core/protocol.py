@@ -69,8 +69,12 @@ class Ledger:
         return sum(m.num_bytes for m in self.messages)
 
 
-def _role_of(client: int, label_holder: int) -> str:
-    return "role3" if client == label_holder else "role1"
+#: the client that also holds the labels (role 3); the others are role 1
+LABEL_HOLDER = 0
+
+
+def _role_of(client: int) -> str:
+    return "role3" if client == LABEL_HOLDER else "role1"
 
 
 # ---------------------------------------------------------------------------
@@ -214,8 +218,7 @@ class StepSchedule:
     tree: Optional[object] = None
 
 
-def step_schedule(num_clients: int, label_holder: int = 0, *,
-                  secure: bool = False,
+def step_schedule(num_clients: int, *, secure: bool = False,
                   compress: Optional[str] = None,
                   tree=None) -> StepSchedule:
     compat.check("schedule", secure=secure, compress=compress, tree=tree)
@@ -232,37 +235,37 @@ def step_schedule(num_clients: int, label_holder: int = 0, *,
         # arrives back down the same edge.
         def _hop(k):
             p = tree.parent(k)
-            return ("role0" if p is None else _role_of(p, label_holder),
+            return ("role0" if p is None else _role_of(p),
                     tree.edge_level(k))
 
         cuts = tuple(
-            MessageSpec(_role_of(k, label_holder), _hop(k)[0],
+            MessageSpec(_role_of(k), _hop(k)[0],
                         f"tree_cut[{_hop(k)[1]}]", "tree_cut", k)
             for k in range(num_clients)
         )
         jacs = tuple(
-            MessageSpec(_hop(k)[0], _role_of(k, label_holder),
+            MessageSpec(_hop(k)[0], _role_of(k),
                         f"tree_jac[{_hop(k)[1]}]", "tree_jac", k)
             for k in range(num_clients)
         )
     else:
         cuts = tuple(
-            MessageSpec(_role_of(k, label_holder), "role0",
+            MessageSpec(_role_of(k), "role0",
                         f"{cut_kind}[{k}]", cut_kind, k)
             for k in range(num_clients)
         )
         jacs = tuple(
-            MessageSpec("role0", _role_of(k, label_holder),
+            MessageSpec("role0", _role_of(k),
                         f"{jac_kind}[{k}]", jac_kind, k)
             for k in range(num_clients)
         )
     key_pubs = tuple(
-        MessageSpec(_role_of(k, label_holder), "role0", f"keyx_pub[{k}]",
+        MessageSpec(_role_of(k), "role0", f"keyx_pub[{k}]",
                     "keyx_pub", k)
         for k in range(num_clients)
     )
     key_bcasts = tuple(
-        MessageSpec("role0", _role_of(k, label_holder), f"keyx_bcast[{k}]",
+        MessageSpec("role0", _role_of(k), f"keyx_bcast[{k}]",
                     "keyx_bcast", k)
         for k in range(num_clients)
     )
@@ -312,8 +315,7 @@ class ServeSchedule:
     cuts: tuple[MessageSpec, ...]
 
 
-def serve_schedule(num_clients: int, label_holder: int = 0, *,
-                   secure: bool = False,
+def serve_schedule(num_clients: int, *, secure: bool = False,
                    compress: Optional[str] = None,
                    tree=None) -> ServeSchedule:
     """The serving schedule for ``num_clients`` feature holders.  Serving
@@ -329,22 +331,22 @@ def serve_schedule(num_clients: int, label_holder: int = 0, *,
                  tree=tree)
     return ServeSchedule(
         prompts=tuple(
-            MessageSpec("role0", _role_of(k, label_holder),
+            MessageSpec("role0", _role_of(k),
                         f"serve_prompt[{k}]", "serve_prompt", k)
             for k in range(num_clients)
         ),
         prefill_cuts=tuple(
-            MessageSpec(_role_of(k, label_holder), "role0",
+            MessageSpec(_role_of(k), "role0",
                         f"serve_prefill_cut[{k}]", "serve_prefill_cut", k)
             for k in range(num_clients)
         ),
         tokens=tuple(
-            MessageSpec("role0", _role_of(k, label_holder),
+            MessageSpec("role0", _role_of(k),
                         f"serve_token[{k}]", "serve_token", k)
             for k in range(num_clients)
         ),
         cuts=tuple(
-            MessageSpec(_role_of(k, label_holder), "role0",
+            MessageSpec(_role_of(k), "role0",
                         f"serve_cut[{k}]", "serve_cut", k)
             for k in range(num_clients)
         ),
@@ -361,7 +363,6 @@ def protocol_step(
     labels,  # role-3 context: an array or a pytree, batch-major
     merge: str,
     *,
-    label_holder: int = 0,
     live_mask: Optional[jnp.ndarray] = None,
     ledger: Optional[Ledger] = None,
     server_takes_batch: bool = False,
@@ -381,11 +382,16 @@ def protocol_step(
     uniform stacked merge for programs with non-uniform cuts (the vlm
     sequence concatenation) — see repro.models.split_program.
 
+    ``live_mask`` (K,) sends the clients it zeroes to their merge's
+    neutral element; every client still gets a jacobian.  The uniform
+    merge always runs ``merge_stacked`` (under all ones when no mask is
+    given); a program ``merge_fn`` receives ``live_mask`` as it is.
+
     Thin wrapper: the numerics live in
-    :class:`repro.runtime.executor.Executor` (serial mode, one microbatch,
-    neutral-element drop semantics) driven over the inline
-    :class:`~repro.transport.SimTransport` — the same execution path that
-    runs the pipelined schedule and the real inproc/multiproc transports.
+    :class:`repro.runtime.executor.Executor` (serial mode, one microbatch)
+    driven over the inline :class:`~repro.transport.SimTransport` — the same
+    execution path that runs the pipelined schedule and the real
+    inproc/multiproc transports.
     """
     # function-level imports: runtime/transport import this module for the
     # schedule and Ledger definitions
@@ -400,11 +406,12 @@ def protocol_step(
                for k in range(K)]
     executor = Executor(
         SimTransport(workers), server_fwd, loss_fn, merge,
-        mode="serial", microbatches=1, label_holder=label_holder,
-        drop_policy="neutral", server_takes_batch=server_takes_batch,
+        mode="serial", microbatches=1, server_takes_batch=server_takes_batch,
         server_aux=server_aux, merge_fn=merge_fn,
         compress=compress, topk_fraction=topk_fraction,
     )
+    if live_mask is None and merge_fn is None:
+        live_mask = jnp.ones((K,), jnp.float32)  # selects merge_stacked
     res = executor.run_step(
         server_params, labels, features=list(features),
         merge_mask=live_mask, ledger=ledger, collect_grads=True,

@@ -362,7 +362,7 @@ def main(argv=None):
                        agg_tree_fanout=args.agg_tree_fanout)
         if report is not None:
             summary["runtime"] = {
-                "mode": report.mode,
+                "mode": args.runtime,
                 "transport": args.transport,
                 "step_time_s": report.step_time_s,
                 "staleness": getattr(report, "staleness", 0),

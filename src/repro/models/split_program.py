@@ -168,7 +168,7 @@ class SplitProgram:
     # -- convenience ---------------------------------------------------------
 
     def protocol_step(self, tower_params, server_params, features, ctx, *,
-                      label_holder: int = 0, live_mask=None, ledger=None):
+                      live_mask=None, ledger=None):
         """Serial reference step on this program's decomposition; returns
         (loss, tower_grads, server_grads, ledger) like ``protocol_step``.
 
@@ -184,7 +184,7 @@ class SplitProgram:
         return protocol_step(
             self.tower_fwds, self.server_fwd, self.loss_fn, tower_params,
             server_params, features, ctx, self.merge,
-            label_holder=label_holder, live_mask=live_mask, ledger=ledger,
+            live_mask=live_mask, ledger=ledger,
             compress=v.compression, topk_fraction=v.topk_fraction,
             **self.executor_kwargs)
 

@@ -25,9 +25,11 @@ simulate_pipelined — including the multi-step cross-step window
 ``simulate_pipelined(steps, cross_step)`` — and the pipelined_step
 wrapper), ``deadline`` (adaptive no-wait windows from per-client arrival
 EWMAs), ``executor`` (the Executor — the ONE execution path that moves
-real payloads over any ``repro.transport`` backend, split into
-``submit_step`` / ``collect_step`` halves; ``protocol_step`` and
-``pipelined_step`` are thin wrappers over it), ``pipeline``
+real payloads over any ``repro.transport`` backend, in two modes: the
+``serial`` barrier, which the serial and pipelined runtimes both run, and
+``nowait``; split into ``submit_step`` / ``collect_step`` halves;
+``protocol_step`` and ``pipelined_step`` are thin wrappers over it),
+``pipeline``
 (``StepPipeline`` — the cross-step window driver: W steps in flight, step
 t+1 tower forwards overlapping step t's server backward and jacobian
 drain; W=1 is the exact per-step barrier, W>1 trains towers on delayed

@@ -36,7 +36,7 @@ from repro.configs.vertical_mlp import MLPSplitConfig
 from repro.core import compat
 from repro.core.costs import mlp_forward_flops, wire_bytes
 from repro.core.merge import collective_bytes_per_merge, merged_dim
-from repro.core.protocol import Ledger
+from repro.core.protocol import LABEL_HOLDER, Ledger
 from repro.runtime.clock import EventClock, Resource
 from repro.runtime.deadline import AdaptiveDeadline
 from repro.runtime.links import LinkModel
@@ -64,7 +64,6 @@ class StepPlan:
     merge: str = "avg"
     cut_elements: int = 0  # per client per microbatch (for collective model)
     bytes_per_elt: int = 4
-    label_holder: int = 0
     # secure aggregation: bytes of ONE public key-exchange group element
     # (costs.key_exchange_bytes); > 0 clocks the one-time setup round —
     # every client uplinks its public value, role 0 relays the K-entry
@@ -306,7 +305,7 @@ def simulate_serial(plan: StepPlan, link: LinkModel, *,
                 r, len(tree.children(r)) * plan.cut_elements * M)
     t += link.server_transfer_s(n_top * plan.cut_bytes * M)  # role-0 NIC rx
     t += link.server_compute_s(plan.server_flops * M)
-    t += 2 * link.transfer_s(plan.label_holder, plan.head_bytes * M)
+    t += 2 * link.transfer_s(LABEL_HOLDER, plan.head_bytes * M)
     t += link.server_transfer_s(n_top * plan.cut_bytes * M)  # role-0 NIC tx
     for k in range(K):
         t += link.transfer_s(k, plan.cut_bytes * M)
@@ -541,17 +540,15 @@ def simulate_pipelined(
     def head_exchange(s: int, m: int) -> None:
         # head output -> role 3 on the label-holder's downlink; the server
         # is FREE to forward the next microbatch meanwhile
-        lh = plan.label_holder
-        _, end = downlink[lh].acquire(
-            clock.now, link.transfer_s(lh, plan.head_bytes))
+        _, end = downlink[LABEL_HOLDER].acquire(
+            clock.now, link.transfer_s(LABEL_HOLDER, plan.head_bytes))
         clock.post(end, lambda: head_return(s, m))
 
     def head_return(s: int, m: int) -> None:
         # head jacobian back on the label-holder's uplink (contends with
         # its own cut uplinks)
-        lh = plan.label_holder
-        _, end = uplink[lh].acquire(
-            clock.now, link.transfer_s(lh, plan.head_bytes))
+        _, end = uplink[LABEL_HOLDER].acquire(
+            clock.now, link.transfer_s(LABEL_HOLDER, plan.head_bytes))
         clock.post(end, lambda: server_bwd(s, m))
 
     def server_bwd(s: int, m: int) -> None:
@@ -699,12 +696,10 @@ def pipelined_step(
     *,
     microbatches: int = 1,
     mode: str = "pipelined",
-    label_holder: int = 0,
     link: Optional[LinkModel] = None,
     plan: Optional[StepPlan] = None,
     deadline_s: Optional[float] = None,
     ema_state: Optional[dict] = None,
-    ema_decay: float = 0.95,
     ledger: Optional[Ledger] = None,
 ):
     """One pipelined training step; drop-in sibling of ``protocol_step``.
@@ -721,7 +716,9 @@ def pipelined_step(
     made each merge; :class:`repro.runtime.executor.Executor` then executes
     the schedule with that liveness over the inline
     :class:`~repro.transport.SimTransport` — the same execution path the
-    real inproc/multiproc transports use.
+    real inproc/multiproc transports use.  The clock's ``"pipelined"`` is
+    the executor's barrier mode, ``"serial"``: a client the clock drops
+    skips its jacobian, and no-wait imputes it from the EMA.
     """
     if mode not in ("pipelined", "nowait"):
         raise ValueError(f"mode must be pipelined|nowait, got {mode!r}")
@@ -749,7 +746,7 @@ def pipelined_step(
             server_flops=3.0 * mlp_forward_flops(
                 [merged_dim(merge, cut_dim, K), cut_dim], mb),
             cut_bytes=mb * cut_dim * 4, head_bytes=mb * 4,
-            merge=merge, cut_elements=mb * cut_dim, label_holder=label_holder,
+            merge=merge, cut_elements=mb * cut_dim,
         )
     if link is None:
         link = LinkModel.uniform(K)
@@ -761,10 +758,7 @@ def pipelined_step(
     workers = [TowerWorker(k, tower_fwd, tower_params[k]) for k in range(K)]
     executor = Executor(
         SimTransport(workers), server_fwd, loss_fn, merge,
-        mode=mode, microbatches=M, label_holder=label_holder,
-        drop_policy="impute" if mode == "nowait" else "fused",
-        ema_decay=ema_decay,
-    )
+        mode="nowait" if mode == "nowait" else "serial", microbatches=M)
     res = executor.run_step(
         server_params, labels, features=list(features),
         liveness=report.live, ema_state=ema_state, ledger=ledger,

@@ -34,15 +34,13 @@ surfaced as ``ExecReport.staleness`` (how many steps were submitted after
 the collected one); W = 1 is staleness 0 and reproduces the serial
 semantics exactly.
 
-Drop policies (what happens to a client absent from a microbatch's merge):
-
-* ``"neutral"`` — serial protocol semantics: the merge masks the client to
-  its strategy's neutral element (``merge_mask``); jacobians still flow to
-  every client.  ``protocol_step``'s ``live_mask``.
-* ``"fused"``   — staleness 0: everyone is live, the fused
-  ``kernels.merge_pool`` path merges the full stack.
-* ``"impute"``  — no-wait: missing seats are filled from the per-client
-  EMA (``repro.core.straggler``); only live clients get a jacobian.
+A barrier step (``mode="serial"``) merges every client's cut with the fused
+``kernels.merge_pool`` kernel, or with ``merge_lib.merge_stacked`` under a
+caller's ``merge_mask`` (``protocol_step``'s ``live_mask``: a masked client
+takes its merge's neutral element and still gets a jacobian).  A no-wait
+step (``mode="nowait"``) first fills the seats a deadline left empty from
+the per-client EMA (``repro.core.straggler``), and only live clients get a
+jacobian.
 
 Liveness comes either from a predetermined matrix (the simulated federation
 clock of ``engine.simulate_pipelined`` — every payload still flows, the
@@ -52,6 +50,7 @@ clock just decides who made the merge) or, over a real transport in
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
@@ -68,8 +67,6 @@ from repro.core.secure_agg import KEYX_GROUP_BYTES
 from repro.runtime.deadline import AdaptiveDeadline
 from repro.transport.base import counted_jit
 from repro.transport.tree import TreeRouter
-
-DROP_POLICIES = ("neutral", "fused", "impute")
 
 # retired (step, mb) first-arrival timestamps kept around so a no-wait
 # straggler's cut landing after its step was collected still feeds the
@@ -166,6 +163,174 @@ class _InflightStep:
     grads: list = field(default_factory=list)  # per-client final tower grads
 
 
+# -- merge topologies, one picked at construction: the frames a microbatch
+# waits for, their merge ``(live_vec, ema_state, cuts) -> (merged,
+# ema_state)`` (run under ``jax.vjp``), the jacobians back, the cut records.
+
+class _Star:
+    """K uniform cuts, one frame per client, stacked and merged at role 0:
+    the fused ``merge_pool`` kernel, after EMA imputation of the clients a
+    deadline left out under no-wait.  A ``merge_mask`` selects
+    ``merge_stacked`` and sends every client a jacobian; otherwise only the
+    live clients get one."""
+
+    def __init__(self, schedule, merge, *, nowait=False, secure=False,
+                 compress=None, topk_fraction=0.25):
+        self.schedule = schedule
+        self.num_clients = self.frames = len(schedule.cuts)
+        self.merge = merge
+        self.mask_ok = not (nowait or secure)
+        self.compress = compress
+        self.topk_fraction = topk_fraction
+        self._jac_residuals: dict = {}  # (client, mb) -> error feedback
+        self._merge = self._impute if nowait else self._fused
+
+    def merge_for(self, liveness, merge_mask) -> Callable:
+        """The merge of one step, given its liveness and mask."""
+        if merge_mask is None:
+            return self._merge
+        if not self.mask_ok:
+            raise ValueError(
+                "merge_mask is barrier-only: under secure aggregation a "
+                "masked client's pairwise masks would not cancel, and "
+                "no-wait takes its liveness from the deadlines")
+        return functools.partial(self._masked, merge_mask)
+
+    def _fused(self, live_vec, ema_state, cuts):
+        return fast_merge(cuts, self.merge), ema_state
+
+    def _impute(self, live_vec, ema_state, cuts):
+        if ema_state is None:
+            K, D = self.num_clients, cuts.shape[-1]
+            ema_state = {"ema": jnp.zeros((K, D), jnp.float32),
+                         "initialized": jnp.zeros((K,), jnp.float32)}
+        imputed, ema_state = straggler_lib.impute_stack(cuts, live_vec,
+                                                        ema_state)
+        return fast_merge(imputed, self.merge), ema_state
+
+    def _masked(self, merge_mask, live_vec, ema_state, cuts):
+        return merge_lib.merge_stacked(cuts, self.merge,
+                                       live_mask=merge_mask), ema_state
+
+    def stack(self, arrived: dict):
+        # a client no-wait left out takes a zero seat, which imputation fills
+        proto = next(iter(arrived.values()))
+        return jnp.stack([arrived.get(k, jnp.zeros_like(proto))
+                          for k in range(self.num_clients)])
+
+    def fanout(self, st, m, cut_grads, live_row, merge_mask) -> list:
+        """Record and count microbatch ``m``'s jacobians; returns the
+        ``(client, jacobian)`` backwards to ship."""
+        backwards = []
+        for spec in self.schedule.jacs:
+            k = spec.client
+            # a client the merge left out skips this microbatch's update
+            if merge_mask is None and live_row[k] <= 0:
+                continue
+            jac_out = cut_grads[k]
+            if self.compress is not None:
+                jac_out, self._jac_residuals[(k, m)] = \
+                    comp_lib.compress_with_feedback(
+                        jac_out, self._jac_residuals.get((k, m)),
+                        self.compress, self.topk_fraction)
+            self._record(st.ledger, spec, jac_out)
+            st.sent_jacs[k] += 1
+            backwards.append((k, jac_out))
+        return backwards
+
+    def record_cut(self, ledger: Ledger, k: int, cut) -> None:
+        self._record(ledger, self.schedule.cuts[k], cut)
+
+    def _record(self, ledger: Ledger, spec, frame) -> None:
+        if self.compress is None:
+            ledger.record_spec(spec, frame)
+        else:  # the codec's wire bytes, not the dense f32 carrier
+            ledger.record_spec_bytes(spec, comp_lib.payload_bytes(
+                frame, self.compress, self.topk_fraction))
+
+    def cut_bytes(self, ledger: Ledger) -> int:
+        # the uplink tag is masked_cut[0] under secure aggregation
+        return ledger.bytes_with_tag(self.schedule.cuts[0].tag)
+
+
+class _ProgramMerge(_Star):
+    """A program ``merge_fn`` over cuts that differ in shape per client
+    (the vlm sequence concatenation): barrier only, so every cut is in,
+    and the list goes to ``merge_fn(cuts, merge_mask)`` unstacked."""
+
+    def __init__(self, schedule, merge_fn: Callable):
+        super().__init__(schedule, None)
+        self.merge_fn = merge_fn
+
+    def merge_for(self, liveness, merge_mask) -> Callable:
+        return lambda live_vec, ema_state, cuts: (
+            self.merge_fn(cuts, merge_mask), ema_state)
+
+    def stack(self, arrived: dict):
+        return [arrived[k] for k in range(self.num_clients)]
+
+    def cut_bytes(self, ledger: Ledger) -> int:
+        # cuts differ in shape per client, so the per-client figure is a mean
+        return int(round(sum(ledger.bytes_with_tag(spec.tag)
+                             for spec in self.schedule.cuts)
+                         / self.num_clients))
+
+
+class _Tree:
+    """An aggregation tree: each of the ``min(F, K)`` top-level frames is
+    its subtree's partial sum, merged with one final sum, and each
+    top-level client gets ONE jacobian that its relays forward down
+    unchanged (the additive merges give every subtree member the same cut
+    gradient; avg's 1/K is already inside it)."""
+
+    def __init__(self, schedule, merge, tree):
+        self.schedule = schedule
+        self.merge = merge
+        self.tree = tree
+        self.frames = len(tree.top_level)
+
+    def merge_for(self, liveness, merge_mask) -> Callable:
+        if liveness is not None or merge_mask is not None:
+            raise ValueError(
+                "tree aggregation is barrier-only: per-client liveness / "
+                "merge_mask cannot be applied to a relay's combined frame "
+                "(the partial sum already folded every subtree member in)")
+        return self._merge
+
+    def _merge(self, live_vec, ema_state, cuts):
+        # avg is the full-tree sum over K (NOT over len(top_level))
+        merged = fast_merge(cuts, "sum")
+        if self.merge == "avg":
+            merged = merged / self.tree.num_clients
+        return merged, ema_state
+
+    def stack(self, arrived: dict):
+        return jnp.stack([arrived[t] for t in self.tree.top_level])
+
+    def fanout(self, st, m, cut_grads, live_row, merge_mask) -> list:
+        # the ledger and sent_jacs count every logical tree edge
+        backwards = []
+        for i, t in enumerate(self.tree.top_level):
+            jac_out = cut_grads[i]
+            for member in self.tree.subtree(t):
+                st.ledger.record_spec(self.schedule.jacs[member], jac_out)
+                st.sent_jacs[member] += 1
+            backwards.append((t, jac_out))
+        return backwards
+
+    def record_cut(self, ledger: Ledger, k: int, cut) -> None:
+        # every edge under a top-level frame carried exactly one frame of
+        # the same uniform shape (tree_cut[l] tags)
+        for member in self.tree.subtree(k):
+            ledger.record_spec(self.schedule.cuts[member], cut)
+
+    def cut_bytes(self, ledger: Ledger) -> int:
+        # tree_cut[0] is shared by every top-level edge: divide out for the
+        # same per-client per-step figure the star reports
+        return (ledger.bytes_with_tag(self.schedule.cuts[0].tag)
+                // len(self.tree.top_level))
+
+
 class Executor:
     """Role-0 server driving training steps over a transport.
 
@@ -187,8 +352,8 @@ class Executor:
       role-0 -> role-3 ``aux_loss`` slot;
     * ``merge_fn(cuts_list, live_mask)`` — replaces the uniform stacked
       merge for programs whose cuts differ in shape per client (the vlm
-      sequence concatenation); requires a barrier mode (no EMA imputation
-      of a non-uniform stack).
+      sequence concatenation); barrier only (no EMA imputation of a
+      non-uniform stack).
 
     Secure aggregation (``secure_agg=True``, ``repro.core.secure_agg``):
     :meth:`setup_secure` runs the one-time in-protocol key exchange (run
@@ -196,47 +361,29 @@ class Executor:
     workers mask every cut uplink at the source and role 0 merges MASKED
     cuts — the pairwise masks cancel in the sum/avg merge, so only the
     aggregate is meaningful and no raw activation is ever observed.
-    Unsupported combinations raise HERE, loudly, rather than silently
-    degrading privacy: a non-additive merge, a program ``merge_fn``
-    (non-uniform cuts have no mask-cancelling sum), and any non-barrier
-    execution (``nowait`` / EMA imputation — a dropped client's masks
-    cannot cancel; there is no dropout-recovery round).
 
     Cut compression (``compress`` = ``"topk"`` | ``"int8"``,
-    ``repro.core.compression``): the workers compress cut uplinks at the
-    source (error feedback per microbatch) and THIS side symmetrically
-    compresses the K jacobian downlinks, with its own per-(client, mb)
-    error-feedback residuals — steps are collected oldest-first, so the
-    per-stream carry is step-sequential at any window W.  The step ledger
-    records the codec's wire bytes (``compression.payload_bytes``) for
-    both directions, which must reconcile exactly with
-    ``costs.wire_bytes``.  Unsupported combinations raise here, loudly:
-    ``secure_agg`` (additive masks do not cancel through
-    quantized/sparsified values — the modular-mask gap Secure Forward
-    Aggregation addresses) and a program ``merge_fn`` (non-uniform cuts
-    have no single per-vector wire frame to audit).
+    ``repro.core.compression``): the workers compress the cut uplinks and
+    role 0 the K jacobian downlinks, each stream with its own per-(client,
+    mb) error feedback, step-sequential at any window W since steps are
+    collected oldest-first.  The ledger records the codec's wire bytes both
+    ways, which must reconcile exactly with ``costs.wire_bytes``.
 
     Tree aggregation (``agg_tree`` = :class:`~repro.runtime.topology.
     AggTree`): the transport is wrapped in a
     :class:`~repro.transport.tree.TreeRouter` (exposed as
-    ``self.transport`` — callers who ``close()`` should close THAT) and
-    the schedule re-routes per the tree — relay workers partial-sum their
-    subtree's cut uplinks, so :meth:`collect_step` gathers only the
-    ``min(F, K)`` top-level combined frames per microbatch, merges them
-    with one final sum (avg divides the full-tree sum by K), and fans each
-    top-level client ONE jacobian that the relays forward down unchanged.
+    ``self.transport`` — callers who ``close()`` should close THAT), whose
+    relay workers partial-sum their subtree's cut uplinks, so role 0
+    gathers and merges ``min(F, K)`` frames per microbatch instead of K.
     ``setup_tree`` ships the one-time ``configure_relay`` round (run
-    automatically on the first ``submit_step``).  Role 0's per-step submit
-    and merge work drops from O(K) to O(F); the Ledger still audits the
-    exact LOGICAL per-edge schedule (``tree_cut[l]``/``tree_jac[l]`` tags:
-    one uniform frame per tree edge per microbatch per direction).
-    Composes with ``secure_agg`` — partial sums of masked cuts stay
-    blinded at relays and the pairwise masks cancel in role 0's full-tree
-    sum.  Unsupported combinations raise HERE, loudly: non-additive merges
-    (max/mul/concat have no partial-sum regrouping), a program
-    ``merge_fn``, compression (codec frames cannot be partial-summed), and
-    any non-barrier execution (a dropped client inside a combined frame
-    cannot be masked out after the fact).
+    automatically on the first ``submit_step``).  The Ledger still audits
+    the exact LOGICAL per-edge schedule (``tree_cut[l]``/``tree_jac[l]``).
+    Composes with ``secure_agg``: partial sums of masked cuts stay blinded
+    at relays and the pairwise masks cancel in role 0's full-tree sum.
+
+    Every unsound composition of these features with each other, with
+    ``nowait``, with a non-additive merge or with a program ``merge_fn``
+    raises HERE, loudly, through :mod:`repro.core.compat`.
 
     Role 0's server work is two compiled programs, each traced once per
     input shape (:attr:`traces`): ``server_step``, the loss of the server
@@ -253,34 +400,26 @@ class Executor:
     """
 
     def __init__(self, transport, server_fwd: Callable, loss_fn: Callable,
-                 merge: str, *, mode: str = "pipelined", microbatches: int = 1,
-                 label_holder: int = 0, drop_policy: Optional[str] = None,
-                 ema_decay: float = 0.95, deadline=None,
-                 server_takes_batch: bool = False, server_aux: bool = False,
-                 merge_fn: Optional[Callable] = None,
+                 merge: str, *, mode: str = "serial", microbatches: int = 1,
+                 deadline=None, server_takes_batch: bool = False,
+                 server_aux: bool = False, merge_fn: Optional[Callable] = None,
                  secure_agg: bool = False, secure_scale: float = 1.0,
                  compress: Optional[str] = None, topk_fraction: float = 0.25,
                  agg_tree=None):
-        if mode not in ("serial", "pipelined", "nowait"):
-            raise ValueError(f"mode must be serial|pipelined|nowait, got {mode!r}")
-        if drop_policy is None:
-            drop_policy = "impute" if mode == "nowait" else "fused"
-        if drop_policy not in DROP_POLICIES:
-            raise ValueError(f"drop_policy must be one of {DROP_POLICIES}")
+        if mode not in ("serial", "nowait"):
+            raise ValueError(f"mode must be serial|nowait, got {mode!r}")
+        nowait = mode == "nowait"
         if compress is not None and compress not in comp_lib.SCHEMES:
             raise ValueError(
                 f"unknown compression scheme {compress!r} (choose from "
                 f"{comp_lib.SCHEMES})")
         # every unsound feature composition rejects through the ONE
         # compat matrix (repro.core.compat) — the rule reasons carry the
-        # full why; mode/drop_policy collapse into the nowait flag (any
-        # non-barrier execution breaks secure masks and tree partial sums)
+        # full why
         compat.check(
             "executor", secure=secure_agg, compress=compress, tree=agg_tree,
-            merge=merge, merge_fn=merge_fn,
-            nowait=mode == "nowait" or drop_policy != "fused",
-            impute=drop_policy == "impute",
-            context=f"Executor(mode={mode!r}, drop_policy={drop_policy!r})")
+            merge=merge, merge_fn=merge_fn, nowait=nowait,
+            context=f"Executor(mode={mode!r})")
         if agg_tree is not None:
             if agg_tree.num_clients != transport.num_clients:
                 raise ValueError(
@@ -293,41 +432,35 @@ class Executor:
         self.transport = transport
         self.server_fwd = server_fwd
         self.loss_fn = loss_fn
-        self.merge = merge
         self.mode = mode
         self.microbatches = microbatches
-        self.label_holder = label_holder
-        self.drop_policy = drop_policy
-        self.ema_decay = ema_decay
         self.server_takes_batch = server_takes_batch
         self.server_aux = server_aux
-        self.merge_fn = merge_fn
         self.secure_agg = secure_agg
         self.secure_scale = secure_scale
-        self.compress = compress
-        self.topk_fraction = topk_fraction
-        # error-feedback residuals for the jacobian downlinks, keyed by
-        # (client, mb): steps are collected oldest-first, so each stream
-        # position's carry advances one step at a time at any window W
-        self._jac_residuals: dict = {}
         self._secure_ready = False
         self._max_secure_step = -1  # highest masked step id (freshness)
         # one-time key-exchange round audit (keyx_pub/keyx_bcast tags)
         self.keyx_ledger = Ledger()
-        # deadline: None -> bootstrap an AdaptiveDeadline from the first
-        # full barrier; float -> static window; AdaptiveDeadline -> as given
-        if deadline is None:
-            self.deadline = AdaptiveDeadline(transport.num_clients)
-            self.static_deadline_s = None
-        elif isinstance(deadline, AdaptiveDeadline):
-            self.deadline = deadline
-            self.static_deadline_s = None
-        else:
-            self.deadline = None
+        # deadline: a float is a static window (reported as deadline_s);
+        # only a no-wait executor adapts one, bootstrapped from the first
+        # full barrier unless an AdaptiveDeadline is given
+        self.deadline = self.static_deadline_s = None
+        if deadline is not None and not isinstance(deadline, AdaptiveDeadline):
             self.static_deadline_s = float(deadline)
-        self._schedule = step_schedule(transport.num_clients, label_holder,
+        elif nowait:
+            self.deadline = deadline or AdaptiveDeadline(transport.num_clients)
+        self._schedule = step_schedule(transport.num_clients,
                                        secure=secure_agg, compress=compress,
                                        tree=agg_tree)
+        if agg_tree is not None:
+            self._topology = _Tree(self._schedule, merge, agg_tree)
+        elif merge_fn is not None:
+            self._topology = _ProgramMerge(self._schedule, merge_fn)
+        else:
+            self._topology = _Star(
+                self._schedule, merge, nowait=nowait, secure=secure_agg,
+                compress=compress, topk_fraction=topk_fraction)
         self._inflight: dict[int, _InflightStep] = {}  # insertion-ordered
         self._retired_first_t: dict[tuple[int, int], float] = {}
         self._traces = {"server_step": 0, "mean": 0}
@@ -536,19 +669,16 @@ class Executor:
 
         ``liveness`` is an (M, K) 0/1 matrix from a simulated clock; without
         it, ``"nowait"`` measures liveness against wall-clock deadlines and
-        other modes barrier on all K cuts.  A ``report`` passed in (the
+        ``"serial"`` barriers on every cut.  ``merge_mask`` (K,) masks
+        clients out of a barrier step's merge.  A ``report`` passed in (the
         simulated clock's) is returned untouched; otherwise a measured
         :class:`ExecReport` is built.
         """
+        topology = self._topology
+        merge = topology.merge_for(liveness, merge_mask)
         if not self._inflight:
             raise RuntimeError("no in-flight step to collect "
                                "(call submit_step first)")
-        if self.agg_tree is not None and (liveness is not None
-                                          or merge_mask is not None):
-            raise ValueError(
-                "tree aggregation is barrier-only: per-client liveness / "
-                "merge_mask cannot be applied to a relay's combined frame "
-                "(the partial sum already folded every subtree member in)")
         st = next(iter(self._inflight.values()))
         transport, K, M = self.transport, self.transport.num_clients, self.microbatches
         schedule = self._schedule
@@ -571,61 +701,16 @@ class Executor:
             live_matrix.append(live_row)
             st.merged.add(m)
 
-            arrived = st.cuts.pop(m, {})
-            if self.agg_tree is not None:
-                # keys are the top-level clients; each frame is its whole
-                # subtree's partial sum
-                cuts_in = jnp.stack([arrived[t]
-                                     for t in self.agg_tree.top_level])
-            elif self.merge_fn is not None:
-                # non-uniform program merge (e.g. vlm sequence concat):
-                # cuts differ in shape per client, so there is no stack to
-                # zero-fill — barrier modes guarantee every cut arrived
-                if len(arrived) < K:
-                    raise RuntimeError(
-                        f"program merge needs every cut; microbatch {m} is "
-                        f"missing clients "
-                        f"{sorted(set(range(K)) - set(arrived))}")
-                cuts_in = [arrived[k] for k in range(K)]
-            else:
-                proto = next(iter(arrived.values()))
-                cuts_in = jnp.stack([
-                    arrived.get(k, jnp.zeros_like(proto)) for k in range(K)
-                ])
-                if self.drop_policy == "impute" and ema_state is None:
-                    ema_state = {
-                        "ema": jnp.zeros((K, cuts_in.shape[-1]), jnp.float32),
-                        "initialized": jnp.zeros((K,), jnp.float32),
-                    }
-
+            cuts_in = topology.stack(st.cuts.pop(m, {}))
             labels_m = jax.tree_util.tree_map(
                 lambda a: a[m * mbsz:(m + 1) * mbsz], st.labels)
             live_vec = jnp.asarray(live_row, jnp.float32)
 
-            def merge(cuts):
-                if self.agg_tree is not None:
-                    # final merge over the top-level partial sums; avg is
-                    # the full-tree sum over K (NOT over len(top_level))
-                    merged = fast_merge(cuts, "sum")
-                    if self.merge == "avg":
-                        merged = merged / K
-                    return merged, ema_state
-                if self.merge_fn is not None:
-                    mask = merge_mask if self.drop_policy == "neutral" else None
-                    return self.merge_fn(cuts, mask), ema_state
-                if self.drop_policy == "impute":
-                    imputed, new_ema = straggler_lib.impute_stack(
-                        cuts, live_vec, ema_state, decay=self.ema_decay)
-                    return fast_merge(imputed, self.merge), new_ema
-                if self.drop_policy == "neutral":
-                    return merge_lib.merge_stacked(
-                        cuts, self.merge, live_mask=merge_mask), ema_state
-                return fast_merge(cuts, self.merge), ema_state
-
             with jax.profiler.TraceAnnotation(
                     "executor.server_step", step=st.step, mb=m):
-                merged, merge_vjp, ema_state = jax.vjp(merge, cuts_in,
-                                                       has_aux=True)
+                merged, merge_vjp, ema_state = jax.vjp(
+                    functools.partial(merge, live_vec, ema_state), cuts_in,
+                    has_aux=True)
                 (loss_m, aux_m), (sg, merged_grad) = self._server_step(
                     server_params, merged, labels_m)
                 cut_grads, = merge_vjp(merged_grad)
@@ -637,53 +722,13 @@ class Executor:
                 st.ledger.record_spec(schedule.aux, aux_m)
             st.ledger.record_spec(schedule.head_jac, head)
 
-            # (client, jacobian) per backward, shipped once the span below
-            # has closed: a transport that runs its workers on this thread
-            # (SimTransport) would otherwise nest their spans inside it
-            backwards = []
+            # the backwards are shipped once the span below has closed: a
+            # transport that runs its workers on this thread (SimTransport)
+            # would otherwise nest their spans inside it
             with jax.profiler.TraceAnnotation(
                     "executor.jac_fanout", step=st.step, mb=m):
-                if self.agg_tree is not None:
-                    # ONE backward per top-level client; relays forward the
-                    # same jacobian down the tree (the additive merges give
-                    # every subtree member the identical cut gradient —
-                    # avg's 1/K is already inside cut_grads).  The ledger
-                    # records every logical tree edge, and sent_jacs counts
-                    # the backward each member receives via the router
-                    # fan-out.
-                    for i, t in enumerate(self.agg_tree.top_level):
-                        jac_out = cut_grads[i]
-                        for member in self.agg_tree.subtree(t):
-                            st.ledger.record_spec(schedule.jacs[member],
-                                                  jac_out)
-                            st.sent_jacs[member] += 1
-                        backwards.append((t, jac_out))
-                else:
-                    for spec in schedule.jacs:
-                        k = spec.client
-                        # serial/neutral semantics: jacobians flow to every
-                        # client; no-wait: a missed deadline skips this
-                        # microbatch's update
-                        if self.drop_policy != "neutral" and live_row[k] <= 0:
-                            continue
-                        jac_out = cut_grads[k]
-                        if self.compress is not None:
-                            # symmetric downlink compression with error
-                            # feedback: the residual this encode drops rides
-                            # into the next step's jacobian for the same
-                            # (client, mb) stream position
-                            jac_out, self._jac_residuals[(k, m)] = \
-                                comp_lib.compress_with_feedback(
-                                    jac_out, self._jac_residuals.get((k, m)),
-                                    self.compress, self.topk_fraction)
-                            st.ledger.record_spec_bytes(
-                                spec, comp_lib.payload_bytes(
-                                    jac_out, self.compress,
-                                    self.topk_fraction))
-                        else:
-                            st.ledger.record_spec(spec, jac_out)
-                        st.sent_jacs[k] += 1
-                        backwards.append((k, jac_out))
+                backwards = topology.fanout(st, m, cut_grads, live_row,
+                                            merge_mask)
             for k, jac_out in backwards:
                 transport.submit(k, {
                     "op": "backward", "step": st.step, "mb": m,
@@ -767,7 +812,7 @@ class Executor:
             st.first_t[m] = now
         if self.deadline is not None:
             spread = now - st.first_t[m]
-            if self.mode == "nowait" and m not in st.merged:
+            if m not in st.merged:
                 # this cut will make the merge — but role 0 may have drained
                 # it long after delivery (busy on an earlier microbatch or
                 # the expired-window sweep), so the raw drain spread can
@@ -775,32 +820,13 @@ class Executor:
                 # that made the merge arrived within it by definition, and
                 # an unclamped observation would let a busy role 0 inflate
                 # the EWMA and loosen the deadline for no client reason.
-                window = self.static_deadline_s
-                if window is None:
-                    window = self.deadline.deadline_s()
+                window = self.deadline.deadline_s()
                 if window is not None:
                     spread = min(spread, window)
             # genuinely late arrivals (mb already merged) observe their raw
             # spread — that is how a recovered straggler earns its way back
             self.deadline.observe(k, spread)
-        if self.agg_tree is not None:
-            # the arriving frame is a top-level client's combined subtree
-            # partial sum; every edge under it carried exactly one frame of
-            # the same uniform shape, so the logical per-edge schedule is
-            # recorded exactly (tree_cut[l] tags)
-            for member in self.agg_tree.subtree(k):
-                st.ledger.record_spec(self._schedule.cuts[member],
-                                      resp["cut"])
-        elif self.compress is not None:
-            # the payload is the worker's lossy encode; the ledger records
-            # the codec's wire bytes (bitmap+values / int8 frame), not the
-            # dense f32 carrier that crosses the loopback for convenience
-            st.ledger.record_spec_bytes(
-                self._schedule.cuts[k],
-                comp_lib.payload_bytes(resp["cut"], self.compress,
-                                       self.topk_fraction))
-        else:
-            st.ledger.record_spec(self._schedule.cuts[k], resp["cut"])
+        self._topology.record_cut(st.ledger, k, resp["cut"])
         if m in st.merged:
             return  # missed the merge: payload discarded at role 0
         st.cuts.setdefault(m, {})[k] = jnp.asarray(resp["cut"])
@@ -815,41 +841,24 @@ class Executor:
     # -- gathering ------------------------------------------------------------
 
     def _gather(self, st: _InflightStep, m: int, liveness):
-        """Collect microbatch ``m``'s cuts; returns (live_row, deadline_s)."""
+        """Collect microbatch ``m``'s frames; returns (live_row, deadline_s)."""
         K = self.transport.num_clients
 
         def have() -> int:
             return len(st.cuts.get(m, {}))
 
-        if self.agg_tree is not None:
-            # barrier on the min(F, K) top-level combined frames — this is
-            # the O(K) -> O(F) role-0 serialization win
-            need = len(self.agg_tree.top_level)
+        if liveness is not None or self.mode == "serial":
+            # barrier on every frame the topology merges; a simulated clock
+            # delivers every cut too, and its matrix decides who made the
+            # merge
+            need = self._topology.frames
             while have() < need:
                 if not self._pump(st.step, None):
                     raise self._idle_error(
-                        "awaiting tree frames",
-                        f"step {st.step} mb {m}: {have()}/{need} top-level "
-                        "frames in")
-            return [1.0] * K, None
-
-        if liveness is not None:
-            # simulated clock: the transport delivers every cut; the given
-            # matrix decides who made the merge
-            while have() < K:
-                if not self._pump(st.step, None):
-                    raise self._idle_error(
                         "awaiting cuts",
-                        f"step {st.step} mb {m}: {have()}/{K} in")
-            return [float(x) for x in liveness[m]], None
-
-        if self.mode != "nowait":
-            while have() < K:
-                if not self._pump(st.step, None):
-                    raise self._idle_error(
-                        "awaiting cuts",
-                        f"step {st.step} mb {m}: {have()}/{K} in")
-            return [1.0] * K, None
+                        f"step {st.step} mb {m}: {have()}/{need} in")
+            return ([1.0] * K if liveness is None
+                    else [float(x) for x in liveness[m]]), None
 
         # real no-wait: grace window after the first arrival
         deadline_used = None
@@ -889,19 +898,6 @@ class Executor:
 
     def _build_report(self, elapsed_s, live_matrix, misses, ledger,
                       deadline_s, staleness) -> ExecReport:
-        K = self.transport.num_clients
-        if self.merge_fn is not None:
-            # non-uniform program merge (e.g. vlm seq-concat): cuts differ
-            # in shape per client, so the per-client figure is a mean
-            cut_bytes = int(round(sum(
-                ledger.bytes_with_tag(f"cut[{k}]") for k in range(K)) / K))
-        else:
-            # the uplink tag is masked_cut[0] under secure aggregation
-            cut_bytes = ledger.bytes_with_tag(self._schedule.cuts[0].tag)
-            if self.agg_tree is not None:
-                # tree_cut[0] is shared by every top-level edge: divide out
-                # for the same per-client per-step figure the star reports
-                cut_bytes //= len(self.agg_tree.top_level)
         return ExecReport(
             mode=self.mode,
             transport=type(self.transport).__name__,
@@ -909,7 +905,7 @@ class Executor:
             microbatches=self.microbatches,
             live=live_matrix,
             misses_per_client=misses,
-            cut_bytes_per_client=cut_bytes,
+            cut_bytes_per_client=self._topology.cut_bytes(ledger),
             deadline_s=deadline_s,
             staleness=staleness,
             tower_platform=self.transport.tower_platform,

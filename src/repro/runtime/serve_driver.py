@@ -33,7 +33,7 @@ class ServeDriver:
     caches, sampling, the cut cache) lives in
     :class:`~repro.serve.split_serve.SplitLMServer`, which drives this."""
 
-    def __init__(self, transport, *, merge: str, label_holder: int = 0,
+    def __init__(self, transport, *, merge: str,
                  ledger: Optional[Ledger] = None, timeout_s: float = 120.0,
                  secure: bool = False, compress: Optional[str] = None,
                  tree=None):
@@ -44,8 +44,7 @@ class ServeDriver:
         # through to serve_schedule, whose compat gate rejects them — the
         # schedule layer is where a masked serving wire becomes unbuildable
         self.schedule: ServeSchedule = serve_schedule(
-            self.num_clients, label_holder, secure=secure,
-            compress=compress, tree=tree)
+            self.num_clients, secure=secure, compress=compress, tree=tree)
         self.ledger = ledger if ledger is not None else Ledger()
         self.timeout_s = timeout_s
         # in-flight response buffers, filled by the shared pump

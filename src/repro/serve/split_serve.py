@@ -162,8 +162,7 @@ class SplitLMServer:
                  cut_cache_bytes: Optional[int] = None,
                  continuous: bool = True,
                  sampling: SamplingParams = SamplingParams(greedy=True),
-                 seed: int = 0, label_holder: int = 0,
-                 ledger: Optional[Ledger] = None,
+                 seed: int = 0, ledger: Optional[Ledger] = None,
                  timeout_s: float = 120.0):
         if cfg.vertical is None:
             raise ValueError(f"{cfg.name}: split serving needs a vertical "
@@ -191,8 +190,7 @@ class SplitLMServer:
                 f"{cfg.name} expects {program.num_clients}")
         self._fns = program.server_serve_fns()  # raises for non-dense
         self.driver = ServeDriver(transport, merge=cfg.vertical.merge,
-                                  label_holder=label_holder, ledger=ledger,
-                                  timeout_s=timeout_s,
+                                  ledger=ledger, timeout_s=timeout_s,
                                   secure=cfg.vertical.secure_aggregation,
                                   compress=cfg.vertical.compression)
         self.cut_cache = CutCache(cut_cache_bytes)

@@ -296,14 +296,19 @@ def train_split(
     is a documented rounding tolerance, not bit-exact.
     """
     from repro.models.split_program import get_program
+    from repro.runtime.engine import MODES
     from repro.runtime.executor import Executor
     from repro.runtime.pipeline import StepPipeline
 
     if cfg.vertical is None:
         raise ValueError("train_split needs a vertical config")
+    if runtime not in MODES:
+        raise ValueError(f"runtime must be one of {MODES}, got {runtime!r}")
     if inflight_steps < 1:
         raise ValueError(f"inflight_steps must be >= 1, got {inflight_steps}")
-    mode = "serial" if runtime == "serial" else runtime
+    # the executor has two modes: no-wait, and the barrier that both the
+    # serial and the (microbatched) pipelined runtime run
+    mode = "nowait" if runtime == "nowait" else "serial"
     M = 1 if runtime == "serial" else microbatches
     W = inflight_steps
 
@@ -423,7 +428,7 @@ def train_split(
             miss = res.report.total_misses if res.report else 0
             print_fn(f"step {res.step:5d}  loss {loss:8.4f}  "
                      f"{dt*1e3:8.1f} ms"
-                     f"  [{transport}/{mode}"
+                     f"  [{transport}/{runtime}"
                      + (f" W={W}" if W > 1 else "")
                      + (f" aux={float(res.aux):.4f}"
                         if res.aux is not None else "")

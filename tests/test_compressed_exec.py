@@ -123,7 +123,7 @@ def test_compressed_matches_serial_reference_mlp(transport_cls, scheme):
     tr = transport_cls(workers)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=M,
+                            microbatches=M,
                             compress=scheme, topk_fraction=FRACTION)
         res = executor.run_step(params["server"], y, features=feats)
     finally:
@@ -172,7 +172,7 @@ def test_compressed_family_matches_serial_and_bounds_plain_dev(
     tr = transport_cls(workers)
     try:
         executor = Executor(tr, program.server_fwd, program.loss_fn,
-                            program.merge, mode="pipelined", microbatches=1,
+                            program.merge, microbatches=1,
                             compress=scheme, topk_fraction=FRACTION,
                             **program.executor_kwargs)
         res = executor.run_step(server_p, ctx, features=feats)
@@ -235,7 +235,7 @@ def test_multiproc_compressed_loopback_matches_and_audits(scheme):
     tr = MultiprocTransport(specs)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=M,
+                            microbatches=M,
                             compress=scheme, topk_fraction=FRACTION)
         res = executor.run_step(params["server"], y, step=0)
     finally:
@@ -270,7 +270,7 @@ def test_error_feedback_residual_carries_across_steps(scheme):
     tr = RecordingSimTransport(workers)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=1,
+                            microbatches=1,
                             compress=scheme, topk_fraction=FRACTION)
         for step in range(2):
             executor.run_step(params["server"], y, step=step, features=feats,
@@ -310,7 +310,7 @@ def test_error_feedback_identical_at_window_1_and_2(scheme):
         losses = []
         try:
             executor = Executor(tr, towers.mlp_tower_apply, loss_fn,
-                                cfg.merge, mode="pipelined", microbatches=1,
+                                cfg.merge, microbatches=1,
                                 compress=scheme, topk_fraction=FRACTION)
             pipe = StepPipeline(executor, window=window)
             for step in range(steps):
@@ -360,7 +360,7 @@ def test_tied_magnitudes_keep_exactly_k_and_reconcile_bytes():
     tr = RecordingSimTransport(workers)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=M,
+                            microbatches=M,
                             compress="topk", topk_fraction=FRACTION)
         res = executor.run_step(params["server"], y, features=feats,
                                 collect_grads=False)
@@ -389,7 +389,7 @@ def test_unsupported_combinations_raise_at_construction():
         Executor(tr, None, None, "avg", secure_agg=True, compress="topk")
     with pytest.raises(ValueError, match="merge_fn"):
         Executor(tr, None, None, "sum", compress="int8",
-                 merge_fn=lambda cuts, m: cuts[0], drop_policy="fused")
+                 merge_fn=lambda cuts, m: cuts[0])
     with pytest.raises(ValueError, match="unknown compression scheme"):
         Executor(tr, None, None, "avg", compress="gzip")
     with pytest.raises(ValueError, match="cannot compose"):

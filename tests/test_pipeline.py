@@ -92,7 +92,7 @@ def test_pipeline_w1_bitexact_vs_run_step(family, arch):
         out = []
         try:
             executor = Executor(tr, program.server_fwd, program.loss_fn,
-                                program.merge, mode="pipelined",
+                                program.merge,
                                 microbatches=1, **program.executor_kwargs)
             pipeline = StepPipeline(executor, window=1)
             for step, b in enumerate(batches):
@@ -180,7 +180,7 @@ def test_pipeline_w2_matches_delayed_gradient_reference():
     ledger_totals = []
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=1)
+                            microbatches=1)
         pipeline = StepPipeline(executor, window=W)
 
         def consume(res):
@@ -267,7 +267,7 @@ def test_pipeline_w2_beats_w1_wallclock_and_sim_predicts_it():
                    for k in range(cfg.num_clients)]
         with InprocTransport(workers) as tr:
             executor = Executor(tr, towers.mlp_tower_apply, slow_loss,
-                                cfg.merge, mode="pipelined", microbatches=1)
+                                cfg.merge, microbatches=1)
             # warm step: jax dispatch/trace outside the timed region
             executor.run_step(params["server"], y_by_step[S],
                               features=feats_by_step[S],
@@ -329,7 +329,7 @@ def test_pipeline_m2_w2_wallclock_band_matches_sim():
                    for k in range(cfg.num_clients)]
         with InprocTransport(workers) as tr:
             executor = Executor(tr, towers.mlp_tower_apply, slow_loss,
-                                cfg.merge, mode="pipelined", microbatches=M)
+                                cfg.merge, microbatches=M)
             executor.run_step(params["server"], y_by_step[S],
                               features=feats_by_step[S],
                               collect_grads=False)

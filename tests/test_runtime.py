@@ -2,6 +2,7 @@
 the analytic collective model, straggler no-wait behavior, and the
 simulated-clock win over the serial schedule."""
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -285,3 +286,68 @@ def test_advise_split_depth_heuristic_unchanged():
     )
     assert r["objective"] == "heuristic"
     assert r["comm_bound"] and r["recommended_tower_layers"] > 1
+
+
+# ---------------------------------------------------------------------------
+# the executor's merge, chosen once per topology and mode
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("case,calls,jacs", [
+    ("barrier", [("fast_merge", "avg")], 3),
+    ("masked", [("merge_stacked", "avg")], 3),
+    ("nowait", [("impute_stack", None), ("fast_merge", "avg")], 2),
+    ("tree", [("fast_merge", "sum")], 3),
+])
+def test_executor_merge_choice(monkeypatch, case, calls, jacs):
+    """Which merge runs: the fused kernel for a barrier step, merge_stacked
+    under a mask (every client still gets a jacobian), EMA imputation then
+    the fused kernel under no-wait (the dropped client gets none), and the
+    tree's final sum over the top-level partial sums."""
+    from repro.configs.vertical_mlp import MLPSplitConfig
+    from repro.core import merge, straggler
+    from repro.runtime import executor as executor_mod
+    from repro.runtime.topology import AggTree
+    from repro.transport import SimTransport, TowerWorker
+
+    cfg = MLPSplitConfig(
+        name="merge_choice", input_dim=12, num_classes=2, num_clients=3,
+        client_feature_sizes=(4, 4, 4), tower_hidden=(8,), cut_dim=8,
+        server_hidden=(8,), merge="avg")
+    params, feats, y, loss_fn = _setup(cfg, batch=8)
+    seen = []
+
+    def counting(name, fn, strategy_of):
+        def wrapped(*args, **kwargs):
+            seen.append((name, strategy_of(args)))
+            return fn(*args, **kwargs)
+        return wrapped
+
+    # the executor's own references only: the kernel's CPU oracle calls
+    # merge_stacked too
+    monkeypatch.setattr(executor_mod, "fast_merge", counting(
+        "fast_merge", executor_mod.fast_merge, lambda a: a[1]))
+    monkeypatch.setattr(executor_mod, "merge_lib", types.SimpleNamespace(
+        merge_stacked=counting("merge_stacked", merge.merge_stacked,
+                               lambda a: a[1])))
+    monkeypatch.setattr(executor_mod, "straggler_lib", types.SimpleNamespace(
+        impute_stack=counting("impute_stack", straggler.impute_stack,
+                              lambda a: None)))
+
+    workers = [TowerWorker(k, towers.mlp_tower_apply, params["towers"][k])
+               for k in range(cfg.num_clients)]
+    ex = executor_mod.Executor(
+        SimTransport(workers), towers.mlp_tower_apply, loss_fn, cfg.merge,
+        mode="nowait" if case == "nowait" else "serial",
+        agg_tree=AggTree(3, fanout=2) if case == "tree" else None)
+    try:
+        res = ex.run_step(
+            params["server"], y, features=feats,
+            merge_mask=jnp.asarray([1.0, 0.0, 1.0]) if case == "masked"
+            else None,
+            liveness=[[1, 0, 1]] if case == "nowait" else None)
+    finally:
+        ex.transport.close()
+    assert seen == calls
+    assert sum(m.tag.startswith(("jac", "tree_jac"))
+               for m in res.ledger.messages) == jacs
+    assert (res.ema_state is not None) == (case == "nowait")

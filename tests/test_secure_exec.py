@@ -93,7 +93,7 @@ def test_secure_matches_plain_mlp(transport_cls, merge):
     tr = transport_cls(workers)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, merge,
-                            mode="pipelined", microbatches=2,
+                            microbatches=2,
                             secure_agg=True)
         res = executor.run_step(params["server"], y, features=feats)
     finally:
@@ -146,7 +146,7 @@ def test_secure_family_matches_serial_protocol(family, arch, transport_cls):
     tr = transport_cls(workers)
     try:
         executor = Executor(tr, program.server_fwd, program.loss_fn,
-                            program.merge, mode="pipelined", microbatches=1,
+                            program.merge, microbatches=1,
                             secure_agg=True, **program.executor_kwargs)
         res = executor.run_step(server_p, ctx, features=feats)
     finally:
@@ -194,7 +194,7 @@ def test_multiproc_secure_loopback_matches_and_reconciles():
     ]
     with MultiprocTransport(specs) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=M,
+                            microbatches=M,
                             secure_agg=True)
         keyx = executor.setup_secure()
         res = executor.run_step(params["server"], y, step=0)
@@ -239,7 +239,7 @@ def test_role0_observations_are_masked_and_leak_less():
     tr = RecordingSimTransport(workers)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=1,
+                            microbatches=1,
                             secure_agg=True, secure_scale=10.0)
         executor.run_step(params["server"], y, features=feats)
     finally:
@@ -289,7 +289,7 @@ def test_executor_rounds_are_fresh_per_step_and_microbatch():
     tr = RecordingSimTransport(workers)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=2,
+                            microbatches=2,
                             secure_agg=True)
         for step in range(2):
             executor.run_step(params["server"], y, step=step, features=feats,
@@ -321,7 +321,7 @@ def test_recycled_step_id_cannot_reuse_masks():
     tr = SimTransport(workers)
     try:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=1,
+                            microbatches=1,
                             secure_agg=True)
         executor.run_step(params["server"], y, features=feats,
                           collect_grads=False)  # default step=0
@@ -349,12 +349,12 @@ def test_unsupported_combinations_raise_at_construction():
         Executor(tr, None, None, "max", secure_agg=True)
     with pytest.raises(ValueError, match="merge_fn"):
         Executor(tr, None, None, "sum", secure_agg=True,
-                 merge_fn=lambda cuts, m: cuts[0], drop_policy="fused")
+                 merge_fn=lambda cuts, m: cuts[0])
     with pytest.raises(ValueError, match="barrier"):
         Executor(tr, None, None, "avg", mode="nowait", secure_agg=True)
+    ex = Executor(tr, None, None, "avg", secure_agg=True)
     with pytest.raises(ValueError, match="barrier"):
-        Executor(tr, None, None, "avg", drop_policy="neutral",
-                 secure_agg=True)
+        ex.collect_step(None, merge_mask=jnp.ones((2,)))
 
 
 def test_train_split_rejects_secure_on_unsupported_paths():

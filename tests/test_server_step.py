@@ -54,7 +54,7 @@ def _executor(program, towers_p, microbatches=1):
                for k in range(program.num_clients)]
     tr = SimTransport(workers)
     executor = Executor(tr, program.server_fwd, program.loss_fn,
-                        program.merge, mode="pipelined",
+                        program.merge,
                         microbatches=microbatches, **program.executor_kwargs)
     return executor, workers, tr
 
@@ -144,7 +144,7 @@ def test_microbatch_mean_of_the_program_gradients(microbatches):
 
     with SimTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, TINY.merge,
-                            mode="pipelined", microbatches=microbatches)
+                            microbatches=microbatches)
         program, calls = executor._server_step, []
 
         def recorded(*args):

@@ -51,7 +51,7 @@ def _traced_spans(transport_cls, log_dir):
     server = params["server"]
     with transport_cls(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, CFG.merge,
-                            mode="pipelined", microbatches=M)
+                            microbatches=M)
         pipeline = StepPipeline(executor, window=1)
         with jax.profiler.trace(str(log_dir), profiler_options=opts):
             for step in range(STEPS):

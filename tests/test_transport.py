@@ -83,7 +83,7 @@ def test_inproc_matches_protocol_step(merge, microbatches):
                for k in range(cfg.num_clients)]
     with InprocTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, merge,
-                            mode="pipelined", microbatches=microbatches)
+                            microbatches=microbatches)
         res = executor.run_step(params["server"], y, features=feats)
 
     np.testing.assert_allclose(res.loss, loss_s, atol=1e-5, rtol=1e-5)
@@ -115,7 +115,7 @@ def test_inproc_local_updates_train():
     losses = []
     with InprocTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=1)
+                            microbatches=1)
         for step in range(steps):
             ks = jax.random.split(jax.random.PRNGKey(step), 2)
             x = jax.random.normal(ks[0], (batch, cfg.input_dim))
@@ -247,7 +247,7 @@ def test_multiproc_loopback_matches_protocol_and_costs():
     ]
     with MultiprocTransport(specs) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=M)
+                            microbatches=M)
         res = executor.run_step(params["server"], y, step=0)
     # close() must not leak children: the shutdown handshake (escalated to
     # terminate/kill for a wedged child) leaves no surviving processes
@@ -403,7 +403,7 @@ def test_worker_compiles_each_program_once_per_shape(case):
                for k in range(cfg.num_clients)]
     with InprocTransport(workers) as tr:
         executor = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                            mode="pipelined", microbatches=1)
+                            microbatches=1)
         for step in range(4):
             executor.run_step(params["server"], y, step=step,
                               features=feats, collect_grads=False)
@@ -585,7 +585,7 @@ def test_sim_transport_matches_inproc():
         tr = transport_cls(workers)
         try:
             executor = Executor(tr, towers.mlp_tower_apply, loss_fn,
-                                cfg.merge, mode="pipelined", microbatches=2)
+                                cfg.merge, microbatches=2)
             return executor.run_step(params["server"], y, features=feats)
         finally:
             tr.close()
@@ -644,7 +644,7 @@ def test_family_split_gradients_match_serial_protocol(family, arch):
         tr = transport_cls(workers)
         try:
             executor = Executor(tr, program.server_fwd, program.loss_fn,
-                                program.merge, mode="pipelined",
+                                program.merge,
                                 microbatches=1, **program.executor_kwargs)
             res = executor.run_step(server_p, ctx, features=feats)
         finally:
@@ -678,7 +678,7 @@ def test_modality_workers_regenerate_features_from_seed(arch):
                for k in range(program.num_clients)]
     with InprocTransport(workers) as tr:
         executor = Executor(tr, program.server_fwd, program.loss_fn,
-                            program.merge, mode="pipelined", microbatches=1,
+                            program.merge, microbatches=1,
                             **program.executor_kwargs)
         res = executor.run_step(server_p, ctx, step=0)  # workers own feats
 
@@ -701,7 +701,7 @@ def test_moe_aux_loss_survives_exchange_and_reconciles():
                for k in range(program.num_clients)]
     with InprocTransport(workers) as tr:
         executor = Executor(tr, program.server_fwd, program.loss_fn,
-                            program.merge, mode="pipelined", microbatches=M,
+                            program.merge, microbatches=M,
                             **program.executor_kwargs)
         res = executor.run_step(server_p, ctx, features=feats)
 

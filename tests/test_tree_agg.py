@@ -150,7 +150,7 @@ def test_tree_ledger_reconciles_with_costs_per_level():
     tr = SimTransport(workers)
     try:
         ex = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                      mode="pipelined", microbatches=M, agg_tree=tree)
+                      microbatches=M, agg_tree=tree)
         res = ex.run_step(params["server"], y, features=feats)
     finally:
         ex.transport.close()
@@ -187,7 +187,7 @@ def test_tree_matches_flat_protocol_step(merge, fanout):
     tr = SimTransport(workers)
     try:
         ex = Executor(tr, towers.mlp_tower_apply, loss_fn, merge,
-                      mode="pipelined", microbatches=2, agg_tree=tree)
+                      microbatches=2, agg_tree=tree)
         res = ex.run_step(params["server"], y, features=feats)
     finally:
         ex.transport.close()
@@ -212,7 +212,7 @@ def test_tree_pipeline_w2_matches_star_w2():
         sigma = params["server"]
         losses = []
         ex = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                      mode="pipelined", microbatches=2, agg_tree=tree)
+                      microbatches=2, agg_tree=tree)
         try:
             pipeline = StepPipeline(ex, window=W)
 
@@ -255,7 +255,7 @@ def test_tree_composes_with_secure_aggregation():
     tr = SimTransport(workers)
     try:
         ex = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                      mode="pipelined", microbatches=2, secure_agg=True,
+                      microbatches=2, secure_agg=True,
                       agg_tree=tree)
         res = ex.run_step(params["server"], y, features=feats)
     finally:
@@ -341,7 +341,7 @@ def test_tree_inproc_w2_with_lagging_child_matches_star():
         tr = InprocTransport(workers)
         try:
             ex = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                          mode="pipelined", microbatches=2, agg_tree=tree)
+                          microbatches=2, agg_tree=tree)
             pipeline = StepPipeline(ex, window=W)
             for s in range(S):
                 res = pipeline.push(params["server"], y, step=s,
@@ -401,7 +401,7 @@ def test_multiproc_tree_matches_and_wedged_relay_close_is_bounded():
     ]
     base = MultiprocTransport(specs)
     ex = Executor(base, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                  mode="pipelined", microbatches=M, agg_tree=tree)
+                  microbatches=M, agg_tree=tree)
     router = ex.transport
     assert isinstance(router, TreeRouter)
     try:
@@ -438,17 +438,18 @@ def test_executor_rejects_unsound_tree_combinations():
         Executor(tr, None, None, "max", agg_tree=tree)
     with pytest.raises(ValueError, match="merge_fn"):
         Executor(tr, None, None, "sum", agg_tree=tree,
-                 merge_fn=lambda cuts, m: cuts[0], drop_policy="fused")
+                 merge_fn=lambda cuts, m: cuts[0])
     with pytest.raises(ValueError, match="compression"):
         Executor(tr, None, None, "sum", agg_tree=tree, compress="int8")
     with pytest.raises(ValueError, match="barrier"):
         Executor(tr, None, None, "avg", mode="nowait", agg_tree=tree)
-    with pytest.raises(ValueError, match="barrier"):
-        Executor(tr, None, None, "avg", drop_policy="neutral", agg_tree=tree)
     with pytest.raises(ValueError, match="tree covers"):
         Executor(tr, None, None, "sum",
                  agg_tree=AggTree(num_clients=3, fanout=2))
-    tr.close()
+    ex = Executor(tr, None, None, "avg", agg_tree=tree)
+    with pytest.raises(ValueError, match="barrier"):
+        ex.collect_step(None, merge_mask=jnp.ones((2,)))
+    ex.transport.close()
 
 
 def test_tree_collect_rejects_liveness_and_merge_mask():
@@ -460,7 +461,7 @@ def test_tree_collect_rejects_liveness_and_merge_mask():
                for k in range(3)]
     tr = SimTransport(workers)
     ex = Executor(tr, towers.mlp_tower_apply, loss_fn, cfg.merge,
-                  mode="pipelined", microbatches=1, agg_tree=tree)
+                  microbatches=1, agg_tree=tree)
     try:
         ex.submit_step(0, y, features=feats)
         with pytest.raises(ValueError, match="barrier-only"):
